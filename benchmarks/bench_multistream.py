@@ -83,6 +83,7 @@ from repro.core.pipeline import VenusConfig, VenusSystem
 from repro.core.session import SessionManager
 from repro.data.video import (OracleEmbedder, PixelEmbedder, VideoWorld,
                               WorldConfig)
+from repro.util import enable_compile_cache
 
 
 def _bench_ingest(n_sessions: int, chunk: int = 64):
@@ -1151,7 +1152,8 @@ def run(n_sessions: int = 4, n_queries: int = 8, *,
                  "timestamp": time.time()})
 
 
-if __name__ == "__main__":
+def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--sessions", type=int, default=4)
     ap.add_argument("--queries", type=int, default=8)
@@ -1213,3 +1215,7 @@ if __name__ == "__main__":
     run(args.sessions, args.queries, smoke=args.smoke, parts=parts,
         json_path=JSON_PATH if args.json else None,
         index_dtype=args.index_dtype)
+
+
+if __name__ == "__main__":
+    main()

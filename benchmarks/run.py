@@ -10,6 +10,8 @@ import argparse
 import sys
 import traceback
 
+from repro.util import enable_compile_cache
+
 from benchmarks import (bench_akr_scaling, bench_fig10, bench_fig11,
                         bench_fig12, bench_ingestion, bench_kernels,
                         bench_multistream, bench_table1, bench_table2,
@@ -30,6 +32,7 @@ SUITES = {
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default="")
     args = ap.parse_args()

@@ -32,7 +32,7 @@ a vectorised device op rather than a per-candidate host loop.
 
 This module and the arena path (``MemoryArena(mesh=...)`` +
 ``kernels.ops``' shard_map scan entries) share one substrate: the
-``launch.sharding.shard_map`` compat symbol, the ``memory_sharding``
+``jax.shard_map`` program shape, the ``memory_sharding``
 slab placement, and the per-shard-top-M + small-gather retrieval shape.
 The arena generalises the (N, d) flat index here to per-session
 ``(S, capacity, ·)`` lanes; this class remains the flat pod-level
@@ -49,8 +49,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.kernels import ops as kops
-from repro.launch.sharding import (memory_sharding, mesh_axis_size,
-                                   shard_map as _shard_map)
+from repro.launch.sharding import memory_sharding, mesh_axis_size
 
 
 @functools.partial(jax.jit, static_argnames=("top_m", "mesh", "mesh_axis"))
@@ -72,7 +71,7 @@ def _sharded_scan(query: jnp.ndarray, index: jnp.ndarray,
         # (K·M,) arrays — the all-gather happens at the consumer
         return top_s, gids
 
-    return _shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(), P(mesh_axis, None), P(mesh_axis)),
         out_specs=(P(mesh_axis), P(mesh_axis)))(query, index, valid)

@@ -1,41 +1,58 @@
 """Kernel dispatch layer.
 
 Every hot-spot op has two implementations: the Pallas TPU kernel and the
-pure-jnp oracle (``ref.py``). The backend is selected by
-``REPRO_KERNEL_BACKEND`` (default ``jnp`` — XLA fuses the references well
-on CPU, and the dry-run lowers the jnp path so cost_analysis reflects
-plain HLO). ``pallas`` switches to the kernels; on CPU they execute in
-interpret mode, on TPU they compile natively.
+pure-jnp oracle (``ref.py``). The platform picks between them: on a TPU
+the kernels compile natively through Mosaic; on the CPU the oracle runs
+(XLA fuses it well there). Any other platform is an error — there is no
+silent fallback. ``set_backend`` is the test hook that pins one side:
+the tests run the kernels on the CPU in interpret mode against the
+oracle, and the chip smoke check runs the oracle on the TPU as its
+reference.
 """
 
 from __future__ import annotations
 
 import functools
-import os
-from typing import NamedTuple, Tuple
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.kernels import ref
-from repro.launch.sharding import mesh_axis_size, shard_map
+from repro.launch.sharding import mesh_axis_size
 
-_BACKEND = os.environ.get("REPRO_KERNEL_BACKEND", "jnp")
+_PLATFORM_BACKEND = {"tpu": "pallas", "cpu": "jnp"}
+_OVERRIDE: Optional[str] = None
+
+
+def _platform() -> str:
+    plat = jax.default_backend()
+    if plat not in _PLATFORM_BACKEND:
+        raise RuntimeError(
+            f"no kernel backend for platform {plat!r}: the Pallas kernels "
+            f"target the TPU and the jnp oracle the CPU")
+    return plat
 
 
 def backend() -> str:
-    return _BACKEND
+    """The backend every dispatch below uses: the ``set_backend``
+    override when one is set, else the platform's own."""
+    return _OVERRIDE or _PLATFORM_BACKEND[_platform()]
 
 
-def set_backend(name: str) -> None:
-    global _BACKEND
-    assert name in ("jnp", "pallas"), name
-    _BACKEND = name
+def set_backend(name: Optional[str]) -> Optional[str]:
+    """Pin the backend ("jnp" or "pallas"); ``None`` restores the
+    platform's choice. Returns the previous pin, for restoring it."""
+    global _OVERRIDE
+    assert name in (None, "jnp", "pallas"), name
+    prev, _OVERRIDE = _OVERRIDE, name
+    return prev
 
 
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    """Kernels run natively on a TPU and interpreted on the CPU."""
+    return _platform() == "cpu"
 
 
 # Dispatch-level launch accounting: every similarity scan entering the
@@ -105,7 +122,7 @@ def reset_scan_counts() -> None:
 def decode_attention(q, k, v, valid, *, scale: float, softcap: float = 0.0,
                      q_per_kv: int = 1) -> jnp.ndarray:
     """q: (B,1,H,D); k/v: (B,C,Hkv,D); valid: (B or 1, C) -> (B,1,H,D)."""
-    if _BACKEND == "pallas":
+    if backend() == "pallas":
         from repro.kernels import decode_attention as dk
         b, c = q.shape[0], k.shape[1]
         vmask = jnp.broadcast_to(valid, (b, c))
@@ -120,7 +137,7 @@ def decode_attention(q, k, v, valid, *, scale: float, softcap: float = 0.0,
 
 def mla_decode_attention(q_abs, q_rope, ckv, krope, valid, *,
                          scale: float) -> jnp.ndarray:
-    if _BACKEND == "pallas":
+    if backend() == "pallas":
         from repro.kernels import decode_attention as dk
         b, c = q_abs.shape[0], ckv.shape[1]
         vmask = jnp.broadcast_to(valid, (b, c))
@@ -138,13 +155,10 @@ def similarity(query, index, *, tau: float, valid
     _scan_counts["similarity"] += 1
     _scan_counts["dense_score_launches"] += 1
     _count_scan_bytes(index)
-    if _BACKEND == "pallas":
+    if backend() == "pallas":
         from repro.kernels import similarity as sk
-        n = index.shape[0]
-        blk = n if n <= sk.DEFAULT_BLK_N else _largest_divisor_blk(
-            n, sk.DEFAULT_BLK_N)
         sims, m, l = sk.similarity_scan(query, index, valid, tau=tau,
-                                        blk_n=blk, interpret=_interpret())
+                                        interpret=_interpret())
         logits = jnp.where(valid[None, :], sims / tau, ref.NEG_INF)
         probs = jnp.exp(logits - m) / jnp.maximum(l, 1e-30)
         return sims.astype(query.dtype), probs
@@ -180,14 +194,17 @@ def _similarity_stack_sharded(query, index, valid, *, tau: float,
                               backend: str, mesh, mesh_axis: str):
     """Fan the stack scan out per shard: each device scans its
     contiguous slot slab with the identical kernel/oracle body; the
-    out_specs stitch the per-shard (S/K, Q, N) outputs back together."""
+    out_specs stitch the per-shard (S/K, Q, N) outputs back together.
+    ``check_vma=False``: a pallas_call's outputs carry no varying-axes
+    annotation, and the bodies are per-shard pure, so every output
+    varies over ``mesh_axis`` exactly as the out_specs say."""
     local = functools.partial(_similarity_stack_local, tau=tau,
                               backend=backend)
     sp = P(mesh_axis, None, None)
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(sp, sp, _valid_spec(valid, mesh_axis)),
-        out_specs=(sp, sp))(query, index, valid)
+        out_specs=(sp, sp), check_vma=False)(query, index, valid)
 
 
 def similarity_stack(query, index, *, tau: float, valid, mesh=None,
@@ -211,7 +228,7 @@ def similarity_stack(query, index, *, tau: float, valid, mesh=None,
         assert query.shape[0] % mesh_axis_size(mesh, mesh_axis) == 0, \
             (query.shape, dict(mesh.shape))
         sims, probs = _similarity_stack_sharded(
-            query, index, valid, tau=tau, backend=_BACKEND, mesh=mesh,
+            query, index, valid, tau=tau, backend=backend(), mesh=mesh,
             mesh_axis=mesh_axis)
         _scan_counts["sharded_stack_launches"] += 1
         _scan_counts["shard_gather_bytes"] += int(
@@ -219,7 +236,7 @@ def similarity_stack(query, index, *, tau: float, valid, mesh=None,
             + probs.size * probs.dtype.itemsize)
         return sims, probs
     return _similarity_stack_local(query, index, valid, tau=tau,
-                                   backend=_BACKEND)
+                                   backend=backend())
 
 
 class FusedRetrieval(NamedTuple):
@@ -269,10 +286,10 @@ def _fused_retrieve_sharded(query, index, valid, targets, *, tau: float,
     local = functools.partial(_fused_retrieve_local, tau=tau,
                               n_topk=n_topk, backend=backend)
     sp = P(mesh_axis, None, None)
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(sp, sp, _valid_spec(valid, mesh_axis), sp),
-        out_specs=(sp,) * 8)(query, index, valid, targets)
+        out_specs=(sp,) * 8, check_vma=False)(query, index, valid, targets)
 
 
 def fused_retrieve_stack(query, index, *, tau: float, valid, targets,
@@ -321,14 +338,14 @@ def fused_retrieve_stack(query, index, *, tau: float, valid, targets,
         assert query.shape[0] % mesh_axis_size(mesh, mesh_axis) == 0, \
             (query.shape, dict(mesh.shape))
         r = _fused_retrieve_sharded(query, index, valid, targets, tau=tau,
-                                    n_topk=n_topk, backend=_BACKEND,
+                                    n_topk=n_topk, backend=backend(),
                                     mesh=mesh, mesh_axis=mesh_axis)
         _scan_counts["sharded_stack_launches"] += 1
         _scan_counts["shard_gather_bytes"] += int(
             sum(a.size * a.dtype.itemsize for a in r))
     else:
         r = _fused_retrieve_local(query, index, valid, targets, tau=tau,
-                                  n_topk=n_topk, backend=_BACKEND)
+                                  n_topk=n_topk, backend=backend())
     cnt, dp, p_last, tv, ti, m, l, p_max = r
     draws = jnp.clip(cnt, 0, n - 1).astype(jnp.int32)
     drawn_p = jnp.where(cnt >= n, p_last, dp)
@@ -337,7 +354,7 @@ def fused_retrieve_stack(query, index, *, tau: float, valid, targets,
 
 def scene_score(frames, weights) -> jnp.ndarray:
     """frames (T,H,W,3) in [0,1] -> φ (T,)."""
-    if _BACKEND == "pallas":
+    if backend() == "pallas":
         from repro.kernels import scene_score as sk
         return sk.scene_score(frames, tuple(weights),
                               interpret=_interpret())

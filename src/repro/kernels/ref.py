@@ -1,9 +1,9 @@
 """Pure-jnp oracles for every Pallas kernel.
 
 These are the correctness references: each kernel's test sweeps shapes and
-dtypes and asserts allclose against the function here. They are also the
-default execution backend on CPU (``REPRO_KERNEL_BACKEND=jnp``), so the
-whole system runs without Pallas in the loop.
+dtypes and asserts allclose against the function here. They are also what
+the dispatch layer (``kernels.ops``) runs on the CPU, so the whole system
+runs there without Pallas in the loop.
 """
 
 from __future__ import annotations
@@ -106,7 +106,10 @@ def similarity_ref(query: jnp.ndarray, index: jnp.ndarray, *, tau: float,
     x = index.astype(f32)
     qn = q * jax.lax.rsqrt(jnp.sum(q * q, -1, keepdims=True) + 1e-12)
     xn = x * jax.lax.rsqrt(jnp.sum(x * x, -1, keepdims=True) + 1e-12)
-    sims = qn @ xn.T                                        # (Q,N)
+    # full fp32 contraction, as in the kernel (the TPU's default for an
+    # f32 XLA dot is a single bf16 pass, which would make the oracle the
+    # less exact of the two)
+    sims = jnp.matmul(qn, xn.T, precision=jax.lax.Precision.HIGHEST)
     logits = jnp.where(valid[None, :], sims / tau, NEG_INF)
     probs = jax.nn.softmax(logits, axis=-1)
     return sims.astype(query.dtype), probs.astype(f32)

@@ -9,9 +9,8 @@ so the temperature-softmax denominator (Eq. 5) comes out of the same pass.
 The wrapper finishes probs = exp(s/τ − m)/l — an O(N) vector epilogue XLA
 fuses with the consumer.
 
-Grid: ``(N/BLK_N,)`` sequential, queries resident in VMEM.
-
-``similarity_scan_stack`` is the cross-session form: a padded stack of S
+``similarity_scan_stack`` is the cross-session form (``similarity_scan``
+is its one-session case): a padded stack of S
 session indices ``(S, capacity, d)`` with per-session valid masks and a
 per-session query block ``(S, Q, d)`` scanned by ONE program over grid
 ``(S, capacity/BLK_N)`` — the multi-tenant edge box's whole query tick
@@ -66,12 +65,45 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import tpu_compiler_params
-from repro.kernels.draws import DRAW_BLK, chunk_cdf
+from repro.kernels.draws import DRAW_BLK, chunk_cdf, lane_at
 from repro.kernels.ref import as_valid_mask
 
 NEG_INF = -1e30
 DEFAULT_BLK_N = 1024
+# top-k bookkeeping inside the fused kernel: lane indices travel as f32
+# (exact below 2^24, and f32 min/max lane reductions lower everywhere);
+# _NO_LANE marks the seed slots, _TAKEN the candidates already selected
+_NO_LANE = float(1 << 30)
+_TAKEN = float("-inf")
+
+
+def _lane_mask(valid: jnp.ndarray) -> jnp.ndarray:
+    """(..., N) bool -> (..., 1, N) int32: the valid mask's kernel
+    layout. A (1, BLK) block of it satisfies the TPU's (8, 128) tiling
+    rule whatever the leading extent (a (1, BLK) block over an (S, N)
+    array does not), and int32 is a layout Mosaic loads natively."""
+    return valid.astype(jnp.int32)[..., None, :]
+
+
+def _online_stats(logit, m_acc, l_acc):
+    """One block of the online softmax: fold (Q, BLK) logits into the
+    running (Q, 1) max and sum-exp."""
+    m_prev = m_acc[...]
+    m_new = jnp.maximum(m_prev, jnp.max(logit, -1, keepdims=True))
+    l_acc[...] = l_acc[...] * jnp.exp(m_prev - m_new) + jnp.sum(
+        jnp.exp(logit - m_new), -1, keepdims=True)
+    m_acc[...] = m_new
+
+
+def _normalised_scores(q_ref, x_ref):
+    """(Q, d) pre-normalised queries × (BLK, d) rows (fp32 or int8,
+    dequantised here) -> (Q, BLK) cosine scores."""
+    q = q_ref[0].astype(jnp.float32)
+    x = x_ref[0].astype(jnp.float32)
+    xn = x * jax.lax.rsqrt(jnp.sum(x * x, -1, keepdims=True) + 1e-12)
+    return jax.lax.dot_general(q, xn, (((1,), (1,)), ((), ())),
+                               preferred_element_type=jnp.float32,
+                               precision=jax.lax.Precision.HIGHEST)
 
 
 def _aligned_blk(n: int, blk_n: int) -> int:
@@ -87,90 +119,18 @@ def _aligned_blk(n: int, blk_n: int) -> int:
     return min(blk_n, n)
 
 
-def _sim_kernel(q_ref, x_ref, valid_ref, sims_ref, m_ref, l_ref,
-                m_acc, l_acc, *, tau, blocks):
-    i = pl.program_id(0)
-
-    @pl.when(i == 0)
-    def _init():
-        m_acc[...] = jnp.full_like(m_acc, NEG_INF)
-        l_acc[...] = jnp.zeros_like(l_acc)
-
-    q = q_ref[...].astype(jnp.float32)            # (Q, d) pre-normalised
-    x = x_ref[...].astype(jnp.float32)            # (BLK, d)
-    valid = valid_ref[0]                          # (BLK,)
-
-    xn = x * jax.lax.rsqrt(jnp.sum(x * x, -1, keepdims=True) + 1e-12)
-    s = jax.lax.dot_general(q, xn, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32)  # (Q, BLK)
-    sims_ref[...] = s.astype(sims_ref.dtype)
-
-    logit = jnp.where(valid[None, :], s / tau, NEG_INF)
-    m_prev = m_acc[...]                           # (Q, 1)
-    m_new = jnp.maximum(m_prev[:, 0], jnp.max(logit, -1))[:, None]
-    corr = jnp.exp(m_prev - m_new)
-    l_acc[...] = l_acc[...] * corr + jnp.sum(
-        jnp.exp(logit - m_new), -1, keepdims=True)
-    m_acc[...] = m_new
-
-    @pl.when(i == blocks - 1)
-    def _final():
-        m_ref[...] = m_acc[...]
-        l_ref[...] = l_acc[...]
-
-
-@functools.partial(jax.jit, static_argnames=("tau", "blk_n", "interpret"))
 def similarity_scan(query, index, valid, *, tau: float,
                     blk_n: int = DEFAULT_BLK_N, interpret: bool = True):
     """query: (Q,d); index: (N,d); valid: (N,) bool.
 
     Returns (sims (Q,N), m (Q,1), l (Q,1)) — cosine scores plus the online
     softmax statistics. probs = exp(sims/τ − m) / l on valid entries.
-    N is zero-padded (invalid lanes) up to a block multiple, the same
-    treatment as the stacked wrapper — any index length works with any
-    block size.
+    The one-session case of ``similarity_scan_stack`` (same kernel, S=1).
     """
-    qn, d = query.shape
-    n = index.shape[0]
-    blk = _aligned_blk(n, blk_n)
-    pad = (-n) % blk
-    if pad:
-        index = jnp.pad(index, ((0, pad), (0, 0)))
-        valid = jnp.pad(valid, (0, pad))
-    npad = n + pad
-    blocks = npad // blk
-
-    q32 = query.astype(jnp.float32)
-    qnorm = q32 * jax.lax.rsqrt(
-        jnp.sum(q32 * q32, -1, keepdims=True) + 1e-12)
-
-    kernel = functools.partial(_sim_kernel, tau=tau, blocks=blocks)
-    sims, m, l = pl.pallas_call(
-        kernel,
-        grid=(blocks,),
-        in_specs=[
-            pl.BlockSpec((qn, d), lambda i: (0, 0)),
-            pl.BlockSpec((blk, d), lambda i: (i, 0)),
-            pl.BlockSpec((1, blk), lambda i: (0, i)),
-        ],
-        out_specs=[
-            pl.BlockSpec((qn, blk), lambda i: (0, i)),
-            pl.BlockSpec((qn, 1), lambda i: (0, 0)),
-            pl.BlockSpec((qn, 1), lambda i: (0, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((qn, npad), jnp.float32),
-            jax.ShapeDtypeStruct((qn, 1), jnp.float32),
-            jax.ShapeDtypeStruct((qn, 1), jnp.float32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((qn, 1), jnp.float32),
-            pltpu.VMEM((qn, 1), jnp.float32),
-        ],
-        compiler_params=tpu_compiler_params(("arbitrary",)),
-        interpret=interpret,
-    )(qnorm, index, valid[None, :])
-    return sims[:, :n], m, l
+    sims, m, l = similarity_scan_stack(query[None], index[None],
+                                       valid[None], tau=tau, blk_n=blk_n,
+                                       interpret=interpret)
+    return sims[0], m[0], l[0]
 
 
 # ---------------------------------------------------------------------------
@@ -187,22 +147,10 @@ def _sim_stack_kernel(q_ref, x_ref, valid_ref, sims_ref, m_ref, l_ref,
         m_acc[...] = jnp.full_like(m_acc, NEG_INF)
         l_acc[...] = jnp.zeros_like(l_acc)
 
-    q = q_ref[0].astype(jnp.float32)              # (Q, d) pre-normalised
-    x = x_ref[0].astype(jnp.float32)              # (BLK, d)
-    valid = valid_ref[0]                          # (BLK,)
-
-    xn = x * jax.lax.rsqrt(jnp.sum(x * x, -1, keepdims=True) + 1e-12)
-    s = jax.lax.dot_general(q, xn, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32)  # (Q, BLK)
+    s = _normalised_scores(q_ref, x_ref)          # (Q, BLK)
     sims_ref[0] = s.astype(sims_ref.dtype)
-
-    logit = jnp.where(valid[None, :], s / tau, NEG_INF)
-    m_prev = m_acc[...]                           # (Q, 1)
-    m_new = jnp.maximum(m_prev[:, 0], jnp.max(logit, -1))[:, None]
-    corr = jnp.exp(m_prev - m_new)
-    l_acc[...] = l_acc[...] * corr + jnp.sum(
-        jnp.exp(logit - m_new), -1, keepdims=True)
-    m_acc[...] = m_new
+    valid = valid_ref[0] != 0                     # (1, BLK)
+    _online_stats(jnp.where(valid, s / tau, NEG_INF), m_acc, l_acc)
 
     @pl.when(i == blocks - 1)
     def _final():
@@ -247,7 +195,7 @@ def similarity_scan_stack(query, index, valid, *, tau: float,
         in_specs=[
             pl.BlockSpec((1, qn, d), lambda s, i: (s, 0, 0)),
             pl.BlockSpec((1, blk, d), lambda s, i: (s, i, 0)),
-            pl.BlockSpec((1, blk), lambda s, i: (s, i)),
+            pl.BlockSpec((1, 1, blk), lambda s, i: (s, 0, i)),
         ],
         out_specs=[
             pl.BlockSpec((1, qn, blk), lambda s, i: (s, 0, i)),
@@ -263,9 +211,10 @@ def similarity_scan_stack(query, index, valid, *, tau: float,
             pltpu.VMEM((qn, 1), jnp.float32),
             pltpu.VMEM((qn, 1), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(("arbitrary", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
-    )(qnorm, index, valid)
+    )(qnorm, index, _lane_mask(valid))
     return sims[:, :, :n], m, l
 
 
@@ -291,18 +240,15 @@ def _fused_stack_kernel(q_ref, x_ref, valid_ref, t_ref,
     crossing-lane probability, and a running top-k merges the block's
     masked scores. Only O(Q·(T+K)) state ever leaves the kernel — the
     (Q, BLK) score tile dies in VMEM.
+
+    Everything here is 2-D over (Q, BLK) or (Q, T|K) with lane
+    reductions that keep their dims — the shapes Mosaic lowers. Per
+    target and per top-k slot the loops are static (T, K ≤ a budget).
     """
     i = pl.program_id(1)                          # 0 .. 2*blocks-1
-    qn = q_ref.shape[1]
-
-    q = q_ref[0].astype(jnp.float32)              # (Q, d) pre-normalised
-    x = x_ref[0].astype(jnp.float32)              # (BLK, d) int8 rows
-    valid = valid_ref[0]                          # (BLK,)  dequantise here
-
-    xn = x * jax.lax.rsqrt(jnp.sum(x * x, -1, keepdims=True) + 1e-12)
-    s = jax.lax.dot_general(q, xn, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32)  # (Q, BLK)
-    logit = jnp.where(valid[None, :], s / tau, NEG_INF)
+    s = _normalised_scores(q_ref, x_ref)          # (Q, BLK)
+    valid = valid_ref[0] != 0                     # (1, BLK)
+    logit = jnp.where(valid, s / tau, NEG_INF)
 
     @pl.when(i == 0)
     def _init():                                  # fresh stats per session
@@ -311,12 +257,7 @@ def _fused_stack_kernel(q_ref, x_ref, valid_ref, t_ref,
 
     @pl.when(i < blocks)
     def _pass1():
-        m_prev = m_acc[...]                       # (Q, 1)
-        m_new = jnp.maximum(m_prev[:, 0], jnp.max(logit, -1))[:, None]
-        corr = jnp.exp(m_prev - m_new)
-        l_acc[...] = l_acc[...] * corr + jnp.sum(
-            jnp.exp(logit - m_new), -1, keepdims=True)
-        m_acc[...] = m_new
+        _online_stats(logit, m_acc, l_acc)
 
     @pl.when(i == blocks - 1)
     def _stats_out():
@@ -329,52 +270,71 @@ def _fused_stack_kernel(q_ref, x_ref, valid_ref, t_ref,
         cnt_acc[...] = jnp.zeros_like(cnt_acc)
         dp_acc[...] = jnp.zeros_like(dp_acc)
         tv_acc[...] = jnp.full_like(tv_acc, NEG_INF)
-        # NEG_INF ties resolve to the lowest lane index, as in a global
-        # top_k — seed the accumulator with indices 0..K-1
-        ti_acc[...] = jax.lax.broadcasted_iota(jnp.int32, ti_acc.shape, 1)
+        ti_acc[...] = jnp.full_like(ti_acc, _NO_LANE)
+
+    def _probs():                                 # bit-equal to the
+        m = m_acc[...]                            # materialised probs
+        l = jnp.maximum(l_acc[...], 1e-30)        # epilogue
+        return jnp.exp(logit - m) / l
 
     @pl.when(i >= blocks)
     def _pass2():
-        m = m_acc[...]                            # finalised stats
-        l = jnp.maximum(l_acc[...], 1e-30)
-        p = jnp.exp(logit - m) / l                # (Q, BLK) — bit-equal
-                                                  # to the materialised
-                                                  # probs epilogue
+        p = _probs()                              # (Q, BLK)
         carry = carry_acc[...]                    # (Q, 1)
-        cdf = chunk_cdf(p.reshape(qn, blk // DRAW_BLK, DRAW_BLK),
-                        carry).reshape(qn, blk)
-        carry_acc[...] = cdf[:, -1:]
-        t = t_ref[0]                              # (Q, T)
-        le = cdf[:, None, :] <= t[:, :, None]     # (Q, T, BLK)
-        cnt_acc[...] += jnp.sum(le.astype(jnp.int32), -1)
-        # drawn probability: p at the unique crossing lane
+        cdf = chunk_cdf(p, carry, roll=pltpu.roll)
+        lane = jax.lax.broadcasted_iota(jnp.int32, cdf.shape, 1)
+        prev = jnp.where(lane == 0, carry, pltpu.roll(cdf, 1, 1))
+        carry_acc[...] = lane_at(cdf, blk - 1)
+        # per target: #{cdf ≤ t} and p at the unique crossing lane
         # (cdf > t and the previous lane's cdf ≤ t)
-        prev = jnp.concatenate([carry, cdf[:, :-1]], -1)
-        cross = (~le) & (prev[:, None, :] <= t[:, :, None])
-        dp_acc[...] += jnp.sum(jnp.where(cross, p[:, None, :], 0.0), -1)
+        t = t_ref[0]                              # (Q, T)
+        tlane = jax.lax.broadcasted_iota(jnp.int32, t.shape, 1)
+        cnt, dp = cnt_acc[...], dp_acc[...]
+        for j in range(t.shape[1]):
+            tj = lane_at(t, j)                    # (Q, 1)
+            le = cdf <= tj
+            cross = jnp.logical_and(jnp.logical_not(le), prev <= tj)
+            cnt = cnt + jnp.where(tlane == j, jnp.sum(
+                le.astype(jnp.float32), -1, keepdims=True), 0.0)
+            dp = dp + jnp.where(tlane == j, jnp.sum(
+                jnp.where(cross, p, 0.0), -1, keepdims=True), 0.0)
+        cnt_acc[...] = cnt
+        dp_acc[...] = dp
 
-        j = i - blocks
-        sv = jnp.where(valid[None, :], s, NEG_INF)
-        gi = j * blk + jax.lax.broadcasted_iota(jnp.int32, sv.shape, 1)
-        cand_v = jnp.concatenate([tv_acc[...], sv], -1)
-        cand_i = jnp.concatenate([ti_acc[...], gi], -1)
-        nv, sel = jax.lax.top_k(cand_v, tv_acc.shape[-1])
+        # running top-k over (acc ∪ block): K rounds of "largest value,
+        # lowest lane among equals" — lax.top_k's order, so the result
+        # is the global top-k of the masked scores, ties to the lowest
+        # lane
+        sv = jnp.where(valid, s, NEG_INF)
+        gi = ((i - blocks) * blk + lane).astype(jnp.float32)
+        av, ai = tv_acc[...], ti_acc[...]
+        klane = jax.lax.broadcasted_iota(jnp.int32, av.shape, 1)
+        nv, ni = av, ai
+        for k in range(av.shape[1]):
+            top = jnp.maximum(jnp.max(sv, -1, keepdims=True),
+                              jnp.max(av, -1, keepdims=True))
+            at = jnp.minimum(
+                jnp.min(jnp.where(sv == top, gi, _NO_LANE), -1,
+                        keepdims=True),
+                jnp.min(jnp.where(av == top, ai, _NO_LANE), -1,
+                        keepdims=True))
+            nv = jnp.where(klane == k, top, nv)
+            ni = jnp.where(klane == k, at, ni)
+            sv = jnp.where(gi == at, _TAKEN, sv)
+            av = jnp.where(ai == at, _TAKEN, av)
         tv_acc[...] = nv
-        ti_acc[...] = jnp.take_along_axis(cand_i, sel, -1)
+        ti_acc[...] = ni
 
     @pl.when(i == blocks + last_blk)
     def _plast():
-        m = m_acc[...]
-        l = jnp.maximum(l_acc[...], 1e-30)
-        p = jnp.exp(logit - m) / l
-        plast_ref[0] = p[:, last_lane:last_lane + 1]
+        plast_ref[0] = lane_at(_probs(), last_lane)
 
     @pl.when(i == 2 * blocks - 1)
     def _final():
-        cnt_ref[0] = cnt_acc[...]
+        cnt_ref[0] = cnt_acc[...].astype(jnp.int32)
         dp_ref[0] = dp_acc[...]
         tv_ref[0] = tv_acc[...]
-        ti_ref[0] = ti_acc[...]
+        ti_ref[0] = ti_acc[...].astype(jnp.int32)
 
 
 @functools.partial(jax.jit,
@@ -418,14 +378,14 @@ def fused_retrieve_scan_stack(query, index, valid, targets, *, tau: float,
         _fused_stack_kernel, tau=tau, blocks=blocks, blk=blk,
         last_blk=(n - 1) // blk, last_lane=(n - 1) % blk)
     xmap = lambda s, i: (s, i % blocks, 0)
-    vmap_ = lambda s, i: (s, i % blocks)
+    vmap_ = lambda s, i: (s, 0, i % blocks)
     out = pl.pallas_call(
         kernel,
         grid=(sn, 2 * blocks),
         in_specs=[
             pl.BlockSpec((1, qn, d), lambda s, i: (s, 0, 0)),
             pl.BlockSpec((1, blk, d), xmap),
-            pl.BlockSpec((1, blk), vmap_),
+            pl.BlockSpec((1, 1, blk), vmap_),
             pl.BlockSpec((1, qn, tn), lambda s, i: (s, 0, 0)),
         ],
         out_specs=[
@@ -450,12 +410,13 @@ def fused_retrieve_scan_stack(query, index, valid, targets, *, tau: float,
             pltpu.VMEM((qn, 1), jnp.float32),
             pltpu.VMEM((qn, 1), jnp.float32),
             pltpu.VMEM((qn, 1), jnp.float32),
-            pltpu.VMEM((qn, tn), jnp.int32),
+            pltpu.VMEM((qn, tn), jnp.float32),
             pltpu.VMEM((qn, tn), jnp.float32),
             pltpu.VMEM((qn, n_topk), jnp.float32),
-            pltpu.VMEM((qn, n_topk), jnp.int32),
+            pltpu.VMEM((qn, n_topk), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(("arbitrary", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
-    )(qnorm, index, valid, targets)
+    )(qnorm, index, _lane_mask(valid), targets)
     return out

@@ -12,35 +12,30 @@ from __future__ import annotations
 from typing import Sequence, Tuple
 
 import jax
-from jax.sharding import AbstractMesh
+from jax.sharding import AxisType
 
 
-def make_abstract_mesh(shape: Sequence[int], axes: Sequence[str]):
-    """Device-free mesh for sharding-rule tests / dry runs.
-
-    ``AbstractMesh`` changed signature across JAX versions: newer ones
-    take ``(axis_sizes, axis_names)``, older ones (≤0.4.x) a single
-    tuple of ``(name, size)`` pairs. Try the new form first so the
-    compat cost disappears once the old API is gone.
-    """
-    shape, axes = tuple(shape), tuple(axes)
-    try:
-        return AbstractMesh(shape, axes)
-    except TypeError:
-        return AbstractMesh(tuple(zip(axes, shape)))
+def _make_mesh(shape: Sequence[int], axes: Sequence[str]):
+    """``jax.make_mesh`` with Auto axes: the arena's slot-sharded
+    ``.at[].set`` scatters and the shard_map scan entries rely on the
+    compiler propagating shardings, which Explicit axes (the default
+    of ``jax.make_mesh`` on the JAX this repository runs) refuse for
+    scatters with a ``ShardingTypeError``."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _make_mesh(shape, axes)
 
 
 def make_host_mesh(model: int = 1):
     """Tiny mesh on whatever devices exist (tests / examples on CPU)."""
     n = len(jax.devices())
     model = min(model, n)
-    return jax.make_mesh((n // model, model), ("data", "model"))
+    return _make_mesh((n // model, model), ("data", "model"))
 
 
 def make_memory_mesh(shards: int = 0):

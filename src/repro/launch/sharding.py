@@ -29,15 +29,6 @@ import jax
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-# One shard_map resolution for the whole repo: the memory-arena scan
-# fan-out (kernels/ops.py) and the pod-level DistributedVenusMemory
-# (core/distributed_memory.py) import THIS symbol, so the two sharded
-# retrieval paths cannot drift across jax versions.
-try:                                   # jax ≥0.5 re-exports at top level
-    shard_map = jax.shard_map
-except AttributeError:                 # jax ≤0.4.x
-    from jax.experimental.shard_map import shard_map
-
 # sentinel for "the FSDP axis" — resolved per mode/mesh
 FSDP = "__fsdp__"
 MODEL = "model"

@@ -36,7 +36,9 @@ from repro.data.video import OracleEmbedder, PixelEmbedder, VideoWorld, \
     WorldConfig
 from repro.kernels import ops as kops
 from repro.kernels import ref
-from repro.kernels.draws import categorical_from_targets, draw_targets
+from repro.kernels.draws import (DRAW_BLK, blockwise_cdf,
+                                  categorical_from_targets, chunk_cdf,
+                                  draw_targets)
 
 
 @pytest.fixture(params=["jnp", "pallas"])
@@ -80,6 +82,10 @@ CASES = [
     dict(S=2, Q=1, N=100, d=8, T=4, K=2, valid_kind="mask", seed=4),
     dict(S=2, Q=2, N=512, d=32, T=8, K=4, valid_kind="mask", seed=5,
          dtype="int8"),
+    # several scan blocks: the CDF carry and the running top-k cross
+    # block boundaries (2500 rows -> three 1024-row blocks)
+    dict(S=2, Q=1, N=2500, d=16, T=8, K=3, valid_kind="sizes", seed=6,
+         sizes=[2500, 1700]),
 ]
 
 
@@ -144,6 +150,71 @@ def test_fused_akr_stops_like_progressive(backend):
             np.testing.assert_array_equal(np.asarray(got.draws[s, q]),
                                           np.asarray(want.draws))
             assert int(got.n_drawn[s, q]) == int(want.n_drawn)
+
+
+def test_fused_topk_ties_go_to_the_lowest_lane(backend):
+    """Exact score ties (duplicate rows, some in different scan blocks)
+    and more top-k slots than valid rows (masked lanes tie at NEG_INF):
+    the fused top-k is ``lax.top_k`` over the masked scores — value
+    descending, ties to the lowest lane — on both backends."""
+    rng = np.random.default_rng(3)
+    d, n = 16, 1300
+    rows = rng.standard_normal((n, d)).astype(np.float32)
+    rows[1100] = rows[7] = rows[600] = rows[3]      # 4-way tie, 2 blocks
+    index = jnp.asarray(np.stack([rows, rows]))
+    query = jnp.asarray(np.stack([rows[3], rows[3]])[:, None])
+    sizes = jnp.asarray([n, 2], jnp.int32)          # lane 1: 2 valid rows
+    fused = kops.fused_retrieve_stack(
+        query, index, tau=0.1, valid=sizes,
+        targets=jnp.zeros((2, 1, 1), jnp.float32), n_topk=6)
+    sims, _ = kops.similarity_stack(query, index, tau=0.1, valid=sizes)
+    vmask = ref.as_valid_mask(sizes, n)
+    masked = jnp.where(vmask[:, None, :], sims, ref.NEG_INF)
+    want_v, want_i = jax.lax.top_k(masked, 6)
+    np.testing.assert_array_equal(np.asarray(fused.topk_i),
+                                  np.asarray(want_i))
+    np.testing.assert_array_equal(np.asarray(fused.topk_v),
+                                  np.asarray(want_v))
+    assert list(np.asarray(fused.topk_i[0, 0, :4])) == [3, 7, 600, 1100]
+
+
+def test_chunk_cdf_is_blocking_invariant():
+    """The draw contract: folding the CDF one scan block at a time
+    (the fused kernel, carry in between) gives the same bits as one
+    fold over the whole vector (the materialised path)."""
+    p = jax.random.uniform(jax.random.key(21), (3, 8 * DRAW_BLK)) / 2000
+    whole = chunk_cdf(p, jnp.zeros((3, 1), jnp.float32))
+    carry, parts = jnp.zeros((3, 1), jnp.float32), []
+    for b in range(0, p.shape[1], 4 * DRAW_BLK):
+        part = chunk_cdf(p[:, b:b + 4 * DRAW_BLK], carry)
+        carry = part[:, -1:]
+        parts.append(part)
+    np.testing.assert_array_equal(np.asarray(whole),
+                                  np.asarray(jnp.concatenate(parts, -1)))
+    np.testing.assert_array_equal(np.asarray(whole[1]),
+                                  np.asarray(blockwise_cdf(p[1])))
+
+
+def test_chunk_cdf_replaces_the_cumsum_fold():
+    """Why the definition changed: the previous chunk fold was
+    ``jnp.cumsum`` within each chunk plus a ``jnp.cumsum`` over chunk
+    totals. Its bits follow XLA's lowering of cumsum (an associative
+    scan on the CPU, ``reduce_window`` on the TPU), and Mosaic cannot
+    lower it inside the fused kernel at all. The log-step scan is built
+    from adds, rolls and selects only, so it is one set of bits on every
+    platform and in the kernel. It is the same quantity: on seeded
+    inputs it agrees with the old fold to fp32 rounding, and with an
+    exact float64 prefix sum to the same order."""
+    p = jax.random.uniform(jax.random.key(22), (4, 6 * DRAW_BLK)) / 1500
+    new = np.asarray(chunk_cdf(p, jnp.zeros((4, 1), jnp.float32)))
+    chunks = p.reshape(4, 6, DRAW_BLK)
+    cc = jnp.cumsum(chunks, axis=-1)
+    ext = jnp.concatenate([jnp.zeros((4, 1)), cc[..., -1][:, :-1]], -1)
+    old = np.asarray((cc + jnp.cumsum(ext, -1)[..., None]).reshape(4, -1))
+    exact = np.cumsum(np.asarray(p, np.float64), axis=-1)
+    np.testing.assert_allclose(new, old, rtol=2e-6, atol=0)
+    np.testing.assert_allclose(new, exact, rtol=2e-6, atol=0)
+    assert np.all(np.diff(new, axis=-1) >= 0)
 
 
 def test_no_dense_output_in_fused_contract():

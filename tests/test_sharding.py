@@ -5,19 +5,18 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.sharding import PartitionSpec as P
+from jax.sharding import AbstractMesh, PartitionSpec as P
 
 from repro.configs import registry
 from repro.launch import sharding as shd
-from repro.launch.mesh import make_abstract_mesh
 from repro.launch.specs import adapt_config, input_specs, params_shape
 from repro.configs.base import get_shape
 
 
 def _mesh(multi=False):
     if multi:
-        return make_abstract_mesh((2, 16, 16), ("pod", "data", "model"))
-    return make_abstract_mesh((16, 16), ("data", "model"))
+        return AbstractMesh((2, 16, 16), ("pod", "data", "model"))
+    return AbstractMesh((16, 16), ("data", "model"))
 
 
 def _spec_of(specs, *path_parts):
