@@ -1,0 +1,11 @@
+"""Mean per ingest tick of the program's own ``embed_insert`` stage time
+(``SessionManager.ingest_tick``'s return), in ms, over the window."""
+
+STAGE = "embed_insert"
+
+
+def read(run):
+    ticks = run.records.get("ingest_ticks", ())
+    if not ticks or STAGE not in ticks[0]:
+        return None
+    return 1e3 * sum(t[STAGE] for t in ticks) / len(ticks)
