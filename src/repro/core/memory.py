@@ -1256,9 +1256,7 @@ class VenusMemory:
         self._if_row_ver = -1
         self.version = 0               # bumped per insert (stack caching)
         self.io_stats = {"full_uploads": 0, "appended_rows": 0,
-                         "member_uploads": 0, "appended_member_rows": 0,
-                         "index_frame_uploads": 0,
-                         "appended_index_frame_rows": 0,
+                         "member_uploads": 0, "index_frame_uploads": 0,
                          "scans": 0, "host_expand_gathers": 0,
                          "device_expand_gathers": 0,
                          "evicted_rows": 0, "reservoir_merges": 0,
@@ -1538,7 +1536,6 @@ class VenusMemory:
                 jnp.asarray(self._members[pos:pos + 1]),
                 jnp.asarray(self._member_count[pos:pos + 1]),
                 jnp.asarray(pos, jnp.int32))
-            self.io_stats["appended_member_rows"] += 1
 
     def _sync_device(self, runs) -> None:
         """Push freshly written host-mirror runs to the device copy.
@@ -1562,8 +1559,6 @@ class VenusMemory:
                     self._member_count[pos:pos + cnt],
                     self._index_frame[pos:pos + cnt], self.window)
                 self.io_stats["appended_rows"] += moved
-                self.io_stats["appended_member_rows"] += moved
-                self.io_stats["appended_index_frame_rows"] += moved
             return
         # bucketed padding past the run is only safe while the memory is
         # a plain append-only prefix (head == 0: padded rows land past
@@ -1595,14 +1590,12 @@ class VenusMemory:
                     self._members_dev, self._member_count_dev,
                     jnp.asarray(rows), jnp.asarray(cnts),
                     jnp.asarray(pos, jnp.int32))
-                self.io_stats["appended_member_rows"] += b
             if self._index_frame_dev is not None:
                 rows = np.zeros((b,), np.int32)
                 rows[:cnt] = self._index_frame[pos:pos + cnt]
                 self._index_frame_dev = _append_id_rows(
                     self._index_frame_dev, jnp.asarray(rows),
                     jnp.asarray(pos, jnp.int32))
-                self.io_stats["appended_index_frame_rows"] += b
 
     # ----------------------------------------------------------------- query
     @property
@@ -1866,8 +1859,7 @@ class MemoryStack:
         self._emb_versions: Optional[Tuple[int, ...]] = None
         self._mem_versions: Optional[Tuple[int, ...]] = None
         self._if_versions: Optional[Tuple[int, ...]] = None
-        self.io_stats = {"stack_builds": 0, "member_stack_builds": 0,
-                         "index_frame_stack_builds": 0}
+        self.io_stats = {"stack_builds": 0}
 
     def __len__(self) -> int:
         return len(self.memories)
@@ -1920,7 +1912,6 @@ class MemoryStack:
             self._members_stack = jnp.stack([t[0] for t in tabs])
             self._counts_stack = jnp.stack([t[1] for t in tabs])
             self._mem_versions = vers
-            self.io_stats["member_stack_builds"] += 1
             self._count_rebuild()
         return self._members_stack, self._counts_stack
 
@@ -1934,7 +1925,6 @@ class MemoryStack:
             self._index_frame_stack = jnp.stack(
                 [m.device_index_frames() for m in self.memories])
             self._if_versions = vers
-            self.io_stats["index_frame_stack_builds"] += 1
             self._count_rebuild()
         return self._index_frame_stack
 
@@ -1988,8 +1978,7 @@ class ArenaStackView:
         self.capacity = arena.capacity
         self.dim = arena.dim
         self.member_cap = arena.member_cap
-        self.io_stats = {"stack_builds": 0, "member_stack_builds": 0,
-                         "index_frame_stack_builds": 0}
+        self.io_stats = {"stack_builds": 0}
 
     def __len__(self) -> int:
         return self.arena.n_sessions

@@ -33,6 +33,7 @@ from repro.core.queryplan import QueryPlan, QuerySpec
 from repro.core.session import (QueryResult, SessionManager, SessionState,
                                 VenusConfig)
 from repro.data.text import tokenize_batch
+from repro.obs import span
 from repro.util import pow2_bucket
 
 __all__ = ["patchify", "MEMEmbedder", "VenusConfig", "QueryResult",
@@ -85,18 +86,20 @@ class MEMEmbedder:
             frames = np.concatenate(
                 [frames, np.zeros((bucket - n,) + frames.shape[1:],
                                   frames.dtype)])
-        patches = patchify(frames, self.patch,
-                           self.mem.cfg.vision.d_model)
-        img = self._img_fn(self.params, patches)[:n]
-        if aux_texts and any(aux_texts):
-            toks, mask = tokenize_batch(list(aux_texts),
-                                        self.mem.cfg.text.vocab_size,
-                                        self.text_max_len)
-            txt = self._txt_fn(self.params, jnp.asarray(toks),
-                               jnp.asarray(mask))
-            img = (img + 0.3 * txt) / np.linalg.norm(
-                np.asarray(img + 0.3 * txt), axis=-1, keepdims=True)
-        return np.asarray(img, np.float32)
+        with span("ingest.embed.patchify"):
+            patches = patchify(frames, self.patch,
+                               self.mem.cfg.vision.d_model)
+        with span("ingest.embed.tower"):
+            img = self._img_fn(self.params, patches)[:n]
+            if aux_texts and any(aux_texts):
+                toks, mask = tokenize_batch(list(aux_texts),
+                                            self.mem.cfg.text.vocab_size,
+                                            self.text_max_len)
+                txt = self._txt_fn(self.params, jnp.asarray(toks),
+                                   jnp.asarray(mask))
+                img = (img + 0.3 * txt) / np.linalg.norm(
+                    np.asarray(img + 0.3 * txt), axis=-1, keepdims=True)
+            return np.asarray(img, np.float32)
 
     def embed_queries(self, texts: Sequence[str]) -> np.ndarray:
         """Batch-encode Q query texts in one text-tower call."""

@@ -72,7 +72,6 @@ re-reads its views after the point where ticks could have run.
 from __future__ import annotations
 
 import functools
-import time
 from dataclasses import dataclass, field
 from typing import (Callable, Dict, List, Mapping, NamedTuple, Optional,
                     Sequence, Tuple)
@@ -84,6 +83,7 @@ import numpy as np
 from repro.core import retrieval as rt
 from repro.core import tiering
 from repro.core.memory import VenusMemory, expand_gather
+from repro.obs import span
 
 
 # ---------------------------------------------------------------------------
@@ -147,9 +147,13 @@ class ExecutionGroup:
 
 @dataclass
 class QueryPlan:
-    """The planner's output: inspectable before (or instead of) running."""
+    """The planner's output: inspectable before (or instead of) running.
+    ``tick`` and ``rids`` label its spans: the manager's query-tick
+    number and the specs' request ids (``SessionManager.plan``)."""
     specs: List[QuerySpec]
     groups: List[ExecutionGroup]
+    tick: int = 0
+    rids: Tuple[int, ...] = ()
 
     @property
     def n_scans(self) -> int:
@@ -463,6 +467,14 @@ class QueryResult:
     draws: np.ndarray              # index draws (or frame ids for "raw")
     n_drawn: int
     mass: float
+    # host seconds, read off the tick's spans: ``embed_query`` the
+    # tick's text-tower time (``venus.execute.embed_text``), the same in
+    # every group's results; ``keys`` the group's PRNG keys
+    # (``venus.execute.keys``); ``similarity`` the launch of the group's
+    # scan (``venus.execute.scan``; the scan's device time is in the
+    # trace); ``sample_expand`` post-processing, expansion and the host
+    # reads of the results (``venus.execute.expand``), which includes
+    # waiting for the scan
     timings: Dict[str, float]
 
 
@@ -543,15 +555,15 @@ def execute_plan(manager, plan: QueryPlan, *, fused: bool = True,
     coarse-less build); PRNG chains advance identically either way."""
     specs = plan.specs
     results: List[Optional[QueryResult]] = [None] * len(specs)
-    t0 = time.perf_counter()
     missing = [j for j, s in enumerate(specs) if s.embedding is None]
     embedded: Dict[int, np.ndarray] = {}
-    if missing:
-        embs = manager.embedder.embed_queries(
-            [specs[j].text for j in missing])
-        embedded = {j: np.asarray(embs[i], np.float32)
-                    for i, j in enumerate(missing)}
-    t_embed = time.perf_counter() - t0
+    with span("execute.embed_text") as emb:
+        if missing:
+            embs = manager.embedder.embed_queries(
+                [specs[j].text for j in missing])
+            embedded = {j: np.asarray(embs[i], np.float32)
+                        for i, j in enumerate(missing)}
+    t_embed = emb.seconds
     for group in plan.groups:
         _execute_group(manager, group, specs, embedded, results, t_embed,
                        fused=fused, coarse=coarse)
@@ -597,16 +609,26 @@ def _group_keys(manager, group: ExecutionGroup, specs, qmax, lanes
 def _execute_group(manager, group: ExecutionGroup, specs, embedded,
                    results, t_embed: float, *, fused: bool = True,
                    coarse: bool = True) -> None:
-    cfg = manager.cfg
-    strat = group.strategy
-    use_fused = fused and strat.name in _FUSED_STRATEGIES
-    sids = group.sids
     # scan-lane order: arena mode scans EVERY slot in slot order (the
     # super-buffers are consumed as-is — zero restacks; freed slots are
     # None hole lanes, masked out by their (0, 0) windows); detached
     # mode scans exactly the group's sessions via the version-cached
     # stack
-    lanes = manager.scan_lanes(sids)
+    lanes = manager.scan_lanes(group.sids)
+    k = group.key
+    with span("execute.group", strategy=k.strategy, budget=k.budget,
+              lanes=len(lanes), qmax=group.qmax):
+        _run_group(manager, group, lanes, specs, embedded, results,
+                   t_embed, fused=fused, coarse=coarse)
+
+
+def _run_group(manager, group: ExecutionGroup, lanes, specs, embedded,
+               results, t_embed: float, *, fused: bool,
+               coarse: bool) -> None:
+    cfg = manager.cfg
+    strat = group.strategy
+    use_fused = fused and strat.name in _FUSED_STRATEGIES
+    sids = group.sids
     lane_of = {sid: si for si, sid in enumerate(lanes)
                if sid is not None}
     ln, qmax = len(lanes), group.qmax
@@ -620,151 +642,153 @@ def _execute_group(manager, group: ExecutionGroup, specs, embedded,
         qcount[si] = len(idxs)
         for qi, j in enumerate(idxs):
             q_stack[si, qi] = _spec_embedding(specs[j], j, embedded)
-    keys = _group_keys(manager, group, specs, qmax, lanes)
+    with span("execute.keys") as sp:
+        keys = _group_keys(manager, group, specs, qmax, lanes)
+    timings["keys"] = sp.seconds
 
     # --- the ONE scan launch for this group ------------------------------
-    t0 = time.perf_counter()
-    stack = manager.memory_stack(lanes)
-    a = stack.arena_view()
-    k = group.key
-    # two-stage trigger: fused group + arena-backed + the hierarchical
-    # tier actually holds consolidated rows (before that the coarse
-    # tier adds nothing the flat scan doesn't cover — and skipping it
-    # keeps the pre-consolidation path bit-identical to a coarse-less
-    # build, which is the `coarse=False` contract too)
-    two_stage = (use_fused and coarse and a is not None
-                 and a.has_consolidated())
-    ts = None
-    if use_fused:
-        # fused path: draws/top-k resolve inside the launch; dense
-        # (S, Q, cap) scores never cross the kernel boundary. Targets
-        # derive from the SAME keys in both modes, so session PRNG
-        # chains advance identically with or without the coarse tier.
-        if strat.stochastic:
-            targets = _targets_from_keys(keys, n=k.budget)
-        else:           # top-k ignores the draw epilogue: dummy targets
-            targets = jnp.zeros((ln, qmax, 1), jnp.float32)
-        n_topk = k.budget if strat.name == "topk" else 1
-        if two_stage:
-            ts = tiering.two_stage_retrieve(
-                a, jnp.asarray(q_stack), targets, tau=k.tau,
-                n_topk=n_topk, topb=getattr(cfg, "coarse_topb", 4))
-            fr = ts.fr
-            manager.io_stats["two_stage_groups"] += 1
+    with span("execute.scan") as sp:
+        stack = manager.memory_stack(lanes)
+        a = stack.arena_view()
+        k = group.key
+        # two-stage trigger: fused group + arena-backed + the hierarchical
+        # tier actually holds consolidated rows (before that the coarse
+        # tier adds nothing the flat scan doesn't cover — and skipping it
+        # keeps the pre-consolidation path bit-identical to a coarse-less
+        # build, which is the `coarse=False` contract too)
+        two_stage = (use_fused and coarse and a is not None
+                     and a.has_consolidated())
+        ts = None
+        if use_fused:
+            # fused path: draws/top-k resolve inside the launch; dense
+            # (S, Q, cap) scores never cross the kernel boundary. Targets
+            # derive from the SAME keys in both modes, so session PRNG
+            # chains advance identically with or without the coarse tier.
+            if strat.stochastic:
+                targets = _targets_from_keys(keys, n=k.budget)
+            else:           # top-k ignores the draw epilogue: dummy targets
+                targets = jnp.zeros((ln, qmax, 1), jnp.float32)
+            n_topk = k.budget if strat.name == "topk" else 1
+            if two_stage:
+                ts = tiering.two_stage_retrieve(
+                    a, jnp.asarray(q_stack), targets, tau=k.tau,
+                    n_topk=n_topk, topb=getattr(cfg, "coarse_topb", 4))
+                fr = ts.fr
+                manager.io_stats["two_stage_groups"] += 1
+            else:
+                fr = stack.fused_retrieve(
+                    jnp.asarray(q_stack), targets, tau=k.tau, n_topk=n_topk)
         else:
-            fr = stack.fused_retrieve(
-                jnp.asarray(q_stack), targets, tau=k.tau, n_topk=n_topk)
-    else:
-        sims, probs = stack.search(jnp.asarray(q_stack), tau=k.tau)
-    if len(sids) == 1:   # single-session group: legacy per-session accounting
-        manager.io_stats["scans"] += 1
-        manager.sessions[sids[0]].memory.io_stats["scans"] += 1
-    else:
-        manager.io_stats["fused_scans"] += 1
-    manager.io_stats["group_scans"] += 1
-    if a is not None and a.n_shards > 1:
-        # this launch fanned out per shard under shard_map (the kernel
-        # entries count bytes; this counts launches at the plan level)
-        manager.io_stats["sharded_group_scans"] += 1
-    timings["similarity"] = time.perf_counter() - t0
+            sims, probs = stack.search(jnp.asarray(q_stack), tau=k.tau)
+        if len(sids) == 1:   # single-session group: per-session accounting
+            manager.io_stats["scans"] += 1
+            manager.sessions[sids[0]].memory.io_stats["scans"] += 1
+        else:
+            manager.io_stats["fused_scans"] += 1
+        manager.io_stats["group_scans"] += 1
+        if a is not None and a.n_shards > 1:
+            # this launch fanned out per shard under shard_map (the kernel
+            # entries count bytes; this counts launches at the plan level)
+            manager.io_stats["sharded_group_scans"] += 1
+    timings["similarity"] = sp.seconds
 
     # --- strategy post-processing + expansion ----------------------------
-    t0 = time.perf_counter()
-    if use_fused:
-        if strat.name == "topk":
-            draws = fr.topk_i
-            sq = draws.shape[:2]
-            if ts is not None:
-                # candidate-local draws → candidate ifr; k may be
-                # clamped to the candidate width, and lanes can hold
-                # fewer valid candidates than k (a consolidated winner
-                # is ONE candidate), so masked slots — recognisable by
-                # their NEG_INF running-top-k score — are dropped
-                # rather than surfacing garbage frame ids
-                valid_d = fr.topk_v > -1e29
-                out = StrategyOutput(
-                    draws, valid_d,
-                    np.asarray(valid_d.sum(-1)), np.full(sq, np.nan))
-                fids_np = np.asarray(tiering.gather_candidate_ifr(
-                    ts.cand_ifr, out.draws))
+    with span("execute.expand") as sp:
+        if use_fused:
+            if strat.name == "topk":
+                draws = fr.topk_i
+                sq = draws.shape[:2]
+                if ts is not None:
+                    # candidate-local draws → candidate ifr; k may be
+                    # clamped to the candidate width, and lanes can hold
+                    # fewer valid candidates than k (a consolidated winner
+                    # is ONE candidate), so masked slots — recognisable by
+                    # their NEG_INF running-top-k score — are dropped
+                    # rather than surfacing garbage frame ids
+                    valid_d = fr.topk_v > -1e29
+                    out = StrategyOutput(
+                        draws, valid_d,
+                        np.asarray(valid_d.sum(-1)), np.full(sq, np.nan))
+                    fids_np = np.asarray(tiering.gather_candidate_ifr(
+                        ts.cand_ifr, out.draws))
+                else:
+                    out = StrategyOutput(draws, jnp.ones(draws.shape, bool),
+                                         np.full(sq, draws.shape[-1]),
+                                         np.full(sq, np.nan))
+                    fids_np = np.asarray(_gather_index_frames(
+                        stack.device_index_frames(), out.draws))
+                ok_np = np.asarray(out.valid)
             else:
-                out = StrategyOutput(draws, jnp.ones(draws.shape, bool),
-                                     np.full(sq, draws.shape[-1]),
-                                     np.full(sq, np.nan))
-                fids_np = np.asarray(_gather_index_frames(
-                    stack.device_index_frames(), out.draws))
-            ok_np = np.asarray(out.valid)
+                u = jnp.asarray(VenusMemory.expand_u(cfg.seed, k.budget),
+                                jnp.int32)
+                if ts is not None:
+                    # candidate-local expansion: draws index the gathered
+                    # (S, Q, C) candidate tables, whose member reservoirs
+                    # came along in the stage-2 gather
+                    if strat.name == "sampling":
+                        valid_d = jnp.ones(fr.draws.shape, bool)
+                        fids, ok = tiering.expand_candidates(
+                            ts.cand_members, ts.cand_counts, fr.draws,
+                            valid_d, u)
+                        sq = fr.draws.shape[:2]
+                        out = StrategyOutput(fr.draws, valid_d,
+                                             np.full(sq, k.budget),
+                                             np.full(sq, np.nan))
+                    else:                                           # akr
+                        akr, fids, ok = tiering.akr_post_candidates(
+                            fr.draws, fr.drawn_p, fr.p_max[..., 0],
+                            ts.cand_members, ts.cand_counts, u,
+                            theta=k.theta, beta=k.beta, n_max=k.budget)
+                        out = StrategyOutput(akr.draws, akr.valid,
+                                             np.asarray(akr.n_drawn),
+                                             np.asarray(akr.mass))
+                else:
+                    members, counts = stack.device_members()
+                    if strat.name == "sampling":
+                        valid_d = jnp.ones(fr.draws.shape, bool)
+                        fids, ok = _expand_stack(members, counts, fr.draws,
+                                                 valid_d, u)
+                        sq = fr.draws.shape[:2]
+                        out = StrategyOutput(fr.draws, valid_d,
+                                             np.full(sq, k.budget),
+                                             np.full(sq, np.nan))
+                    else:                                           # akr
+                        akr, fids, ok = _fused_akr_post(
+                            fr.draws, fr.drawn_p, fr.p_max[..., 0], members,
+                            counts, u, theta=k.theta, beta=k.beta,
+                            n_max=k.budget)
+                        out = StrategyOutput(akr.draws, akr.valid,
+                                             np.asarray(akr.n_drawn),
+                                             np.asarray(akr.mass))
+                manager.io_stats["device_expands"] += 1
+                fids_np, ok_np = np.asarray(fids), np.asarray(ok)
         else:
-            u = jnp.asarray(VenusMemory.expand_u(cfg.seed, k.budget),
-                            jnp.int32)
-            if ts is not None:
-                # candidate-local expansion: draws index the gathered
-                # (S, Q, C) candidate tables, whose member reservoirs
-                # came along in the stage-2 gather
-                if strat.name == "sampling":
-                    valid_d = jnp.ones(fr.draws.shape, bool)
-                    fids, ok = tiering.expand_candidates(
-                        ts.cand_members, ts.cand_counts, fr.draws,
-                        valid_d, u)
-                    sq = fr.draws.shape[:2]
-                    out = StrategyOutput(fr.draws, valid_d,
-                                         np.full(sq, k.budget),
-                                         np.full(sq, np.nan))
-                else:                                           # akr
-                    akr, fids, ok = tiering.akr_post_candidates(
-                        fr.draws, fr.drawn_p, fr.p_max[..., 0],
-                        ts.cand_members, ts.cand_counts, u,
-                        theta=k.theta, beta=k.beta, n_max=k.budget)
-                    out = StrategyOutput(akr.draws, akr.valid,
-                                         np.asarray(akr.n_drawn),
-                                         np.asarray(akr.mass))
-            else:
-                members, counts = stack.device_members()
-                if strat.name == "sampling":
-                    valid_d = jnp.ones(fr.draws.shape, bool)
-                    fids, ok = _expand_stack(members, counts, fr.draws,
-                                             valid_d, u)
-                    sq = fr.draws.shape[:2]
-                    out = StrategyOutput(fr.draws, valid_d,
-                                         np.full(sq, k.budget),
-                                         np.full(sq, np.nan))
-                else:                                           # akr
-                    akr, fids, ok = _fused_akr_post(
-                        fr.draws, fr.drawn_p, fr.p_max[..., 0], members,
-                        counts, u, theta=k.theta, beta=k.beta,
-                        n_max=k.budget)
-                    out = StrategyOutput(akr.draws, akr.valid,
-                                         np.asarray(akr.n_drawn),
-                                         np.asarray(akr.mass))
-            manager.io_stats["device_expands"] += 1
-            fids_np, ok_np = np.asarray(fids), np.asarray(ok)
-    else:
-        emb_stack, valid = stack.device_stack()
-        ctx = StrategyContext(
-            sims=sims, probs=probs, valid=valid, emb=emb_stack, keys=keys,
-            total_frames=np.asarray(
-                [manager.sessions[s].stats["frames_seen"]
-                 if s is not None else 0 for s in lanes], np.int64),
-            key=group.key, qcount=qcount)
+            emb_stack, valid = stack.device_stack()
+            ctx = StrategyContext(
+                sims=sims, probs=probs, valid=valid, emb=emb_stack, keys=keys,
+                total_frames=np.asarray(
+                    [manager.sessions[s].stats["frames_seen"]
+                     if s is not None else 0 for s in lanes], np.int64),
+                key=group.key, qcount=qcount)
 
-        if strat.expand == "members":
-            members, counts = stack.device_members()
-            u = jnp.asarray(VenusMemory.expand_u(cfg.seed, k.budget),
-                            jnp.int32)
-            out, fids, ok = strat.run_expand(ctx, members, counts, u)
-            manager.io_stats["device_expands"] += 1
-            fids_np, ok_np = np.asarray(fids), np.asarray(ok)
-        else:
-            out = strat.run(ctx)
-            ok_np = np.asarray(out.valid)
-            if strat.expand == "index":
-                fids_np = np.asarray(_gather_index_frames(
-                    stack.device_index_frames(), out.draws))
-            else:                               # raw: draws ARE frame ids
-                fids_np = np.asarray(out.draws)
-    draws_np = np.asarray(out.draws)
-    n_drawn, mass = np.asarray(out.n_drawn), np.asarray(out.mass)
-    timings["sample_expand"] = time.perf_counter() - t0
+            if strat.expand == "members":
+                members, counts = stack.device_members()
+                u = jnp.asarray(VenusMemory.expand_u(cfg.seed, k.budget),
+                                jnp.int32)
+                out, fids, ok = strat.run_expand(ctx, members, counts, u)
+                manager.io_stats["device_expands"] += 1
+                fids_np, ok_np = np.asarray(fids), np.asarray(ok)
+            else:
+                out = strat.run(ctx)
+                ok_np = np.asarray(out.valid)
+                if strat.expand == "index":
+                    fids_np = np.asarray(_gather_index_frames(
+                        stack.device_index_frames(), out.draws))
+                else:                               # raw: draws ARE frame ids
+                    fids_np = np.asarray(out.draws)
+        draws_np = np.asarray(out.draws)
+        n_drawn, mass = np.asarray(out.n_drawn), np.asarray(out.mass)
+    timings["sample_expand"] = sp.seconds
 
     for sid in sids:
         si = lane_of[sid]

@@ -44,7 +44,6 @@ from __future__ import annotations
 
 import contextlib
 import os
-import time
 import weakref
 from dataclasses import dataclass
 from typing import Dict, List, Mapping, Optional, Sequence, Tuple
@@ -61,6 +60,7 @@ from repro.core.queryplan import (QueryPlan, QueryResult, QuerySpec,
                                   build_plan, execute_plan)
 from repro.core.scene import Partition, StreamSegmenter
 from repro.core.standing import Alert, StandingRegistry
+from repro.obs import span
 
 # live managers, so test harnesses can reset every launch/transfer
 # counter between tests without threading references around
@@ -219,9 +219,11 @@ class SessionState:
 def segment_stage(state: SessionState, chunk: np.ndarray) -> List[Partition]:
     """① scene segmentation: archive the chunk, return closed partitions."""
     chunk = np.asarray(chunk, np.float32)
-    state.frames.append(chunk)
+    with span("ingest.segment.archive"):
+        state.frames.append(chunk)
     state.stats["frames_seen"] += len(chunk)
-    closed = state.segmenter.ingest(jnp.asarray(chunk))
+    with span("ingest.segment.scores"):
+        closed = state.segmenter.ingest(jnp.asarray(chunk))
     state.pending.extend(chunk)
     return closed
 
@@ -291,34 +293,35 @@ def commit_jobs(sessions: Mapping[int, SessionState], embedder,
     ``io_stats``."""
     if not jobs:
         return 0
-    # fail fast on eviction="none" sessions about to overflow: raising
-    # here — before the embed call and the deferred scatter — names the
-    # session and the fix, instead of a deep-in-scatter shape error
-    # after embedding work is already spent
-    incoming: Dict[int, int] = {}
-    for j in jobs:
-        incoming[j.sid] = incoming.get(j.sid, 0) + len(j.frame_ids)
-    for sid, n_new in incoming.items():
-        mem = sessions[sid].memory
-        if mem.eviction.name == "none" and mem.size + n_new > mem.capacity:
-            raise RuntimeError(
-                f"session {sid}: memory full ({mem.size} rows + {n_new} "
-                f"incoming > capacity {mem.capacity}) — enable eviction "
-                f"or consolidation (VenusConfig(eviction='sliding_window'"
-                f" | 'cluster_merge' | 'consolidate'))")
-    frames = np.concatenate([j.frames for j in jobs])
-    ids = np.concatenate([j.frame_ids for j in jobs])
-    aux = None
-    if any(j.aux_texts for j in jobs):
-        aux = []
+    with span("ingest.embed"):
+        # fail fast on eviction="none" sessions about to overflow: raising
+        # here — before the embed call and the deferred scatter — names the
+        # session and the fix, instead of a deep-in-scatter shape error
+        # after embedding work is already spent
+        incoming: Dict[int, int] = {}
         for j in jobs:
-            aux.extend(j.aux_texts or [""] * len(j.frame_ids))
-    embs = embedder.embed_frames(frames, aux, frame_ids=ids)
+            incoming[j.sid] = incoming.get(j.sid, 0) + len(j.frame_ids)
+        for sid, n_new in incoming.items():
+            mem = sessions[sid].memory
+            if mem.eviction.name == "none" and mem.size + n_new > mem.capacity:
+                raise RuntimeError(
+                    f"session {sid}: memory full ({mem.size} rows + {n_new} "
+                    f"incoming > capacity {mem.capacity}) — enable eviction "
+                    f"or consolidation (VenusConfig(eviction='sliding_window'"
+                    f" | 'cluster_merge' | 'consolidate'))")
+        frames = np.concatenate([j.frames for j in jobs])
+        ids = np.concatenate([j.frame_ids for j in jobs])
+        aux = None
+        if any(j.aux_texts for j in jobs):
+            aux = []
+            for j in jobs:
+                aux.extend(j.aux_texts or [""] * len(j.frame_ids))
+        embs = embedder.embed_frames(frames, aux, frame_ids=ids)
     arenas = {id(a): a for a in
               (sessions[j.sid].memory.arena for j in jobs)
               if a is not None}
     new_by_sid: Dict[int, List[np.ndarray]] = {}
-    with contextlib.ExitStack() as stack:
+    with span("ingest.insert"), contextlib.ExitStack() as stack:
         for a in arenas.values():
             stack.enter_context(a.deferred_appends())
         off = 0
@@ -332,7 +335,8 @@ def commit_jobs(sessions: Mapping[int, SessionState], embedder,
             st.stats["frames_embedded"] += n
             off += n
     if standing is not None:
-        standing.evaluate(sessions, new_by_sid, io_stats)
+        with span("ingest.standing"):
+            standing.evaluate(sessions, new_by_sid, io_stats)
     return len(ids)
 
 
@@ -396,6 +400,8 @@ class SessionManager:
         # must be folded here first to stay monotonic)
         self.closed_frame_stats: Dict[str, int] = {}
         self._arena_stack: Optional[ArenaStackView] = None
+        # plans made so far: a plan's tick number joins its spans
+        self.query_ticks = 0
         _LIVE_MANAGERS.add(self)
 
     def reset_io_stats(self, *, include_memories: bool = True) -> None:
@@ -498,28 +504,36 @@ class SessionManager:
     def ingest_tick(self, chunks: Mapping[int, np.ndarray]
                     ) -> Dict[str, float]:
         """Consume one chunk per stream; embed everything that closed
-        across ALL streams in one batched MEM call. Returns stage
-        timings for the tick."""
-        t0 = time.perf_counter()
-        closed_by_sid = {sid: segment_stage(self.sessions[sid], chunk)
-                         for sid, chunk in chunks.items()}
-        t_seg = time.perf_counter()
-        jobs: List[EmbedJob] = []
-        for sid, closed in closed_by_sid.items():
-            st = self.sessions[sid]
-            for part in closed:
-                jobs.append(cluster_stage(st, part, self.aux_models,
-                                          self.annotation_fn))
-            release_pending(st, closed)
-        t_clu = time.perf_counter()
-        n_emb = commit_jobs(self.sessions, self.embedder, jobs,
-                            standing=self.standing,
-                            io_stats=self.io_stats)
-        n_trim = self._trim_archives(chunks.keys())
-        t_emb = time.perf_counter()
-        return {"segment": t_seg - t0, "cluster": t_clu - t_seg,
-                "embed_insert": t_emb - t_clu, "embedded": float(n_emb),
-                "trimmed": float(n_trim)}
+        across ALL streams in one batched MEM call. Returns the tick's
+        stage times in seconds, read off its spans: ``segment``
+        (``venus.ingest.segment``), ``cluster`` (``venus.ingest.cluster``)
+        and ``embed_insert`` (from the end of clustering to the end of
+        ``venus.ingest.trim``: embedding, the arena scatter, standing
+        queries and the archive trim), and of it ``trim``
+        (``venus.ingest.trim``); and the keyframes ``embedded`` and
+        archive frames ``trimmed``."""
+        with span("ingest_tick", streams=len(chunks),
+                  frames=sum(len(c) for c in chunks.values())):
+            with span("ingest.segment") as seg:
+                closed_by_sid = {
+                    sid: segment_stage(self.sessions[sid], chunk)
+                    for sid, chunk in chunks.items()}
+            jobs: List[EmbedJob] = []
+            with span("ingest.cluster") as clu:
+                for sid, closed in closed_by_sid.items():
+                    st = self.sessions[sid]
+                    for part in closed:
+                        jobs.append(cluster_stage(st, part, self.aux_models,
+                                                  self.annotation_fn))
+                    release_pending(st, closed)
+            n_emb = commit_jobs(self.sessions, self.embedder, jobs,
+                                standing=self.standing,
+                                io_stats=self.io_stats)
+            with span("ingest.trim") as trim:
+                n_trim = self._trim_archives(chunks.keys())
+        return {"segment": seg.seconds, "cluster": clu.seconds,
+                "embed_insert": trim.end - clu.end, "trim": trim.seconds,
+                "embedded": float(n_emb), "trimmed": float(n_trim)}
 
     def flush(self, sids: Optional[Sequence[int]] = None) -> None:
         """Close every open partition and embed the remainder batched."""
@@ -599,12 +613,20 @@ class SessionManager:
     # preserve the per-session PRNG chains draw-for-draw (see
     # tests/test_crosssession.py + tests/test_queryplan.py).
 
-    def plan(self, specs: Sequence[QuerySpec]) -> QueryPlan:
+    def plan(self, specs: Sequence[QuerySpec], *,
+             rids: Sequence[int] = ()) -> QueryPlan:
         """Group specs into execution groups (one fused scan each).
         Passing the live sessions lets the planner reject plans that
         could only fail deep in execution (e.g. ``uniform`` against a
-        window-evicting session with no spill tier)."""
-        return build_plan(specs, self.cfg, sessions=self.sessions)
+        window-evicting session with no spill tier). Each plan is one
+        query tick: it takes the manager's next tick number, which its
+        ``venus.plan`` and ``venus.execute`` spans carry, with the
+        request ids ``rids`` of its specs when the caller has them."""
+        self.query_ticks += 1
+        with span("plan", tick=self.query_ticks):
+            plan = build_plan(specs, self.cfg, sessions=self.sessions)
+        plan.tick, plan.rids = self.query_ticks, tuple(rids)
+        return plan
 
     def execute(self, plan: QueryPlan, *, fused: bool = True,
                 coarse: bool = True) -> List[QueryResult]:
@@ -619,7 +641,9 @@ class SessionManager:
         coarse-tier path even when the arena holds consolidated summary
         rows (the flat-scan escape hatch — bit-identical to a build
         without a coarse tier)."""
-        return execute_plan(self, plan, fused=fused, coarse=coarse)
+        with span("execute", tick=plan.tick,
+                  rids=";".join(map(str, plan.rids))):
+            return execute_plan(self, plan, fused=fused, coarse=coarse)
 
     def query_specs(self, specs: Sequence[QuerySpec]) -> List[QueryResult]:
         """Convenience: ``execute(plan(specs))``."""

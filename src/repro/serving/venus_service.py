@@ -114,7 +114,8 @@ class VenusService:
         """The retrieval plan one service tick compiles to — inspectable
         before anything runs (``plan.n_scans`` == number of execution
         groups == number of fused scans)."""
-        return self.manager.plan([q.to_spec() for q in queries])
+        return self.manager.plan([q.to_spec() for q in queries],
+                                 rids=[q.rid for q in queries])
 
     def submit(self, queries: Sequence[StreamQuery]) -> List[Request]:
         """Compile the tick's queries into ONE plan (the planner groups
