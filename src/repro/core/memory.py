@@ -1241,6 +1241,11 @@ class VenusMemory:
         self._members = np.zeros((capacity, member_cap), np.int32)
         self._member_count = np.zeros((capacity,), np.int32)
         self._index_frame = np.zeros((capacity,), np.int32)
+        # per-row trim horizon: the smallest frame id the row references
+        # (its index frame and its count-masked members), kept in step
+        # with the three tables above so ``min_live_frame`` reads one
+        # int32 per row instead of the whole member table
+        self._row_lo = np.zeros((capacity,), np.int32)
         self._scene_id = np.zeros((capacity,), np.int32)
         self._size = 0
         self._head = 0          # physical position of the oldest row
@@ -1260,7 +1265,7 @@ class VenusMemory:
                          "scans": 0, "host_expand_gathers": 0,
                          "device_expand_gathers": 0,
                          "evicted_rows": 0, "reservoir_merges": 0,
-                         "consolidated_rows": 0}
+                         "consolidated_rows": 0, "reservoir_sampled": 0}
 
     def reset_io_stats(self) -> None:
         """Zero the transfer/scan counters in place (the dict identity is
@@ -1331,14 +1336,15 @@ class VenusMemory:
         for j, member_frames in enumerate(member_lists):
             members = np.asarray(member_frames, np.int32)
             m = len(members)
-            if m > self.member_cap:            # uniform reservoir
-                keep = self._rng.choice(m, self.member_cap, replace=False)
-                members = members[np.sort(keep)]
+            if m > self.member_cap:
+                members = members[self._reservoir(members, ids[j])]
                 m = self.member_cap
             pj = (tail + j) % self.capacity
             self._members[pj, :m] = members
             self._members[pj, m:] = 0      # no stale ids past the count
             self._member_count[pj] = m
+        for pos, _off, cnt in runs:
+            self._refresh_row_lo(pos, cnt)
         self._size += n
         self.version += 1
         self._sync_device(runs)
@@ -1347,6 +1353,36 @@ class VenusMemory:
                 self._mark_blocks_dirty(pos, cnt)
             self._refresh_block_summaries()
         return (tail + np.arange(n)) % self.capacity
+
+    def _reservoir(self, members: np.ndarray, index_frame: int
+                   ) -> np.ndarray:
+        """Sorted positions of the ``member_cap`` members an oversized
+        cluster keeps: its index frame, when it is a member, plus a
+        uniform draw without replacement from the others; a uniform draw
+        from all of them otherwise."""
+        self.io_stats["reservoir_sampled"] += 1
+        m, at = len(members), np.flatnonzero(members == index_frame)
+        if not at.size:
+            return np.sort(self._rng.choice(m, self.member_cap,
+                                            replace=False))
+        rest = self._rng.choice(m - 1, self.member_cap - 1, replace=False)
+        rest += rest >= at[0]               # skip the index frame's place
+        return np.sort(np.append(rest, at[0]))
+
+    def _refresh_row_lo(self, pos: int, cnt: int) -> None:
+        """Recompute the trim horizon of physical rows ``[pos, pos+cnt)``
+        from their index frames and count-masked members (only the
+        columns some row's count reaches are read)."""
+        rows = slice(pos, pos + cnt)
+        counts = self._member_count[rows]
+        lo = self._index_frame[rows].copy()
+        k = int(counts.max(initial=0))
+        if k:
+            live = np.arange(k)[None, :] < counts[:, None]
+            np.minimum(lo, np.where(live, self._members[rows, :k],
+                                    np.iinfo(np.int32).max).min(1),
+                       out=lo)
+        self._row_lo[rows] = lo
 
     def _advance_head(self, need: int) -> None:
         """Sliding-window eviction: drop the ``need`` oldest rows by
@@ -1513,6 +1549,8 @@ class VenusMemory:
             ct = int(self._member_count[pt])
             self._members[pt, ct:ct + take] = self._members[pe, :take]
             self._member_count[pt] = ct + take
+            self._row_lo[pt] = min(int(self._row_lo[pt]),
+                                   int(self._members[pe, :take].min()))
             self.io_stats["reservoir_merges"] += 1
             touched.add(pt)
         for pt in sorted(touched):
@@ -1623,15 +1661,15 @@ class VenusMemory:
         Consolidated summary rows count as live references too: their
         merged reservoirs are what a two-stage query expands, so their
         frame windows pin the archive exactly like fine reservoirs do.
-        An empty memory returns int64-max: it constrains nothing."""
+        Each row's part is kept in ``_row_lo``, so this reads one int32
+        per live row. An empty memory returns int64-max: it constrains
+        nothing."""
         lo = int(np.iinfo(np.int64).max)
-        if self._size:
-            phys = (self._head + np.arange(self._size)) % self.capacity
-            lo = int(self._index_frame[phys].min())
-            cnt = self._member_count[phys]
-            live = np.arange(self.member_cap)[None, :] < cnt[:, None]
-            if live.any():
-                lo = min(lo, int(self._members[phys][live].min()))
+        if self._size:             # the window: one or two ring slices
+            run1 = min(self._size, self.capacity - self._head)
+            lo = int(self._row_lo[self._head:self._head + run1].min())
+            if run1 < self._size:
+                lo = min(lo, int(self._row_lo[:self._size - run1].min()))
         if self.n_coarse and self._coarse_csize:
             lo = min(lo, int(self._coarse_fid_lo[:self._coarse_csize]
                              .min()))
