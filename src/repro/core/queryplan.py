@@ -83,6 +83,7 @@ import numpy as np
 from repro.core import retrieval as rt
 from repro.core import tiering
 from repro.core.memory import VenusMemory, expand_gather
+from repro.kernels import ops as kops
 from repro.obs import span
 
 
@@ -474,7 +475,9 @@ class QueryResult:
     # scan (``venus.execute.scan``; the scan's device time is in the
     # trace); ``sample_expand`` post-processing, expansion and the host
     # reads of the results (``venus.execute.expand``), which includes
-    # waiting for the scan
+    # waiting for the scan; under a sharded arena also ``shard_gather``,
+    # the part of it that reads the answers back from every chip
+    # (``venus.execute.shard_gather``)
     timings: Dict[str, float]
 
 
@@ -606,6 +609,20 @@ def _group_keys(manager, group: ExecutionGroup, specs, qmax, lanes
     return jnp.stack(key_rows)
 
 
+def _to_host(arrays, sharded: bool, timings: Dict[str, float]) -> list:
+    """A group's answers as numpy arrays. Under a sharded arena they
+    come back from every chip: they are awaited first, so that the
+    ``execute.shard_gather`` span times the readback alone, and its
+    seconds go into ``timings["shard_gather"]``."""
+    if not sharded:
+        return [np.asarray(x) for x in arrays]
+    jax.block_until_ready(arrays)
+    with span("execute.shard_gather") as sp:
+        out = [np.asarray(x) for x in arrays]
+    timings["shard_gather"] = sp.seconds
+    return out
+
+
 def _execute_group(manager, group: ExecutionGroup, specs, embedded,
                    results, t_embed: float, *, fused: bool = True,
                    coarse: bool = True) -> None:
@@ -650,6 +667,9 @@ def _run_group(manager, group: ExecutionGroup, lanes, specs, embedded,
     with span("execute.scan") as sp:
         stack = manager.memory_stack(lanes)
         a = stack.arena_view()
+        sharded = a is not None and a.n_shards > 1
+        if sharded:
+            gathered = kops.scan_counts()["shard_gather_bytes"]
         k = group.key
         # two-stage trigger: fused group + arena-backed + the hierarchical
         # tier actually holds consolidated rows (before that the coarse
@@ -686,10 +706,12 @@ def _run_group(manager, group: ExecutionGroup, lanes, specs, embedded,
         else:
             manager.io_stats["fused_scans"] += 1
         manager.io_stats["group_scans"] += 1
-        if a is not None and a.n_shards > 1:
+        if sharded:
             # this launch fanned out per shard under shard_map (the kernel
             # entries count bytes; this counts launches at the plan level)
             manager.io_stats["sharded_group_scans"] += 1
+            manager.io_stats["shard_gather_bytes"] += (
+                kops.scan_counts()["shard_gather_bytes"] - gathered)
     timings["similarity"] = sp.seconds
 
     # --- strategy post-processing + expansion ----------------------------
@@ -706,18 +728,17 @@ def _run_group(manager, group: ExecutionGroup, lanes, specs, embedded,
                     # their NEG_INF running-top-k score — are dropped
                     # rather than surfacing garbage frame ids
                     valid_d = fr.topk_v > -1e29
-                    out = StrategyOutput(
-                        draws, valid_d,
-                        np.asarray(valid_d.sum(-1)), np.full(sq, np.nan))
-                    fids_np = np.asarray(tiering.gather_candidate_ifr(
-                        ts.cand_ifr, out.draws))
+                    out = StrategyOutput(draws, valid_d, valid_d.sum(-1),
+                                         np.full(sq, np.nan))
+                    fids = tiering.gather_candidate_ifr(ts.cand_ifr,
+                                                        out.draws)
                 else:
                     out = StrategyOutput(draws, jnp.ones(draws.shape, bool),
                                          np.full(sq, draws.shape[-1]),
                                          np.full(sq, np.nan))
-                    fids_np = np.asarray(_gather_index_frames(
-                        stack.device_index_frames(), out.draws))
-                ok_np = np.asarray(out.valid)
+                    fids = _gather_index_frames(
+                        stack.device_index_frames(), out.draws)
+                ok = out.valid
             else:
                 u = jnp.asarray(VenusMemory.expand_u(cfg.seed, k.budget),
                                 jnp.int32)
@@ -740,8 +761,7 @@ def _run_group(manager, group: ExecutionGroup, lanes, specs, embedded,
                             ts.cand_members, ts.cand_counts, u,
                             theta=k.theta, beta=k.beta, n_max=k.budget)
                         out = StrategyOutput(akr.draws, akr.valid,
-                                             np.asarray(akr.n_drawn),
-                                             np.asarray(akr.mass))
+                                             akr.n_drawn, akr.mass)
                 else:
                     members, counts = stack.device_members()
                     if strat.name == "sampling":
@@ -758,10 +778,8 @@ def _run_group(manager, group: ExecutionGroup, lanes, specs, embedded,
                             counts, u, theta=k.theta, beta=k.beta,
                             n_max=k.budget)
                         out = StrategyOutput(akr.draws, akr.valid,
-                                             np.asarray(akr.n_drawn),
-                                             np.asarray(akr.mass))
+                                             akr.n_drawn, akr.mass)
                 manager.io_stats["device_expands"] += 1
-                fids_np, ok_np = np.asarray(fids), np.asarray(ok)
         else:
             emb_stack, valid = stack.device_stack()
             ctx = StrategyContext(
@@ -777,17 +795,16 @@ def _run_group(manager, group: ExecutionGroup, lanes, specs, embedded,
                                 jnp.int32)
                 out, fids, ok = strat.run_expand(ctx, members, counts, u)
                 manager.io_stats["device_expands"] += 1
-                fids_np, ok_np = np.asarray(fids), np.asarray(ok)
             else:
                 out = strat.run(ctx)
-                ok_np = np.asarray(out.valid)
+                ok = out.valid
                 if strat.expand == "index":
-                    fids_np = np.asarray(_gather_index_frames(
-                        stack.device_index_frames(), out.draws))
+                    fids = _gather_index_frames(
+                        stack.device_index_frames(), out.draws)
                 else:                               # raw: draws ARE frame ids
-                    fids_np = np.asarray(out.draws)
-        draws_np = np.asarray(out.draws)
-        n_drawn, mass = np.asarray(out.n_drawn), np.asarray(out.mass)
+                    fids = out.draws
+        fids_np, ok_np, draws_np, n_drawn, mass = _to_host(
+            (fids, ok, out.draws, out.n_drawn, out.mass), sharded, timings)
     timings["sample_expand"] = sp.seconds
 
     for sid in sids:

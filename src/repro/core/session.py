@@ -60,6 +60,8 @@ from repro.core.queryplan import (QueryPlan, QueryResult, QuerySpec,
                                   build_plan, execute_plan)
 from repro.core.scene import Partition, StreamSegmenter
 from repro.core.standing import Alert, StandingRegistry
+from repro.launch.mesh import make_memory_mesh
+from repro.launch.sharding import mesh_axis_size
 from repro.obs import span
 
 # live managers, so test harnesses can reset every launch/transfer
@@ -128,6 +130,11 @@ class VenusConfig:
     spill_segment_frames: int = 64
     spill_cache_segments: int = 4
     host_retain: Optional[int] = None
+    # how many chips the arena's slot axis spans: above 1 the manager
+    # builds make_memory_mesh(memory_shards) and the arena holds its
+    # slots in that many contiguous slabs, one per chip (ARCHITECTURE.md
+    # "Sharded arena"); 1 keeps everything on the default device
+    memory_shards: int = 1
     # querying (Eq. 5-7)
     tau: float = 0.1
     theta: float = 0.9
@@ -136,6 +143,9 @@ class VenusConfig:
     seed: int = 0
 
     def __post_init__(self):
+        if self.memory_shards < 1:
+            raise ValueError(
+                f"memory_shards must be >= 1, got {self.memory_shards}")
         if self.spill_segment_frames < 1:
             raise ValueError(
                 f"spill_segment_frames must be >= 1, got "
@@ -366,11 +376,22 @@ class SessionManager:
         # PR-2 detached memories + version-cached MemoryStack path.
         # mesh= shards the arena's slot axis over the mesh's "model"
         # axis (slabs of contiguous slots per device; the fused scans
-        # fan out per shard under shard_map). double_buffer defaults on
-        # whenever a mesh is given — ingest scatters target the back
-        # buffer set so they overlap the fused query launches — and can
-        # be forced either way explicitly.
+        # fan out per shard under shard_map). cfg.memory_shards > 1
+        # builds that mesh here; a mesh passed with it must agree, and
+        # with memory_shards == 1 a passed mesh (the tests' host meshes)
+        # decides alone. double_buffer defaults on whenever a mesh is
+        # given — ingest scatters target the back buffer set so they
+        # overlap the fused query launches — and can be forced either
+        # way explicitly.
         self.use_arena = use_arena
+        if cfg.memory_shards > 1:
+            if mesh is None:
+                mesh = make_memory_mesh(cfg.memory_shards)
+            elif mesh_axis_size(mesh, "model") != cfg.memory_shards:
+                raise ValueError(
+                    f"memory_shards={cfg.memory_shards} but the mesh "
+                    f"passed has {mesh_axis_size(mesh, 'model')} shards "
+                    f"on its 'model' axis")
         self.mesh = mesh
         self.double_buffer = ((mesh is not None) if double_buffer is None
                               else double_buffer)
@@ -379,11 +400,15 @@ class SessionManager:
         # scan per query tick" invariant (tests/benches assert on these);
         # group_scans counts every executor launch regardless of S;
         # stack_rebuilds counts device-side restacks of session buffers
-        # (MUST stay 0 in arena mode — the zero-restack invariant)
+        # (MUST stay 0 in arena mode — the zero-restack invariant);
+        # sharded_group_scans counts launches that fanned out per slab
+        # and shard_gather_bytes the epilogue bytes they stitched back
+        # from the slabs (kops' shard_gather_bytes, per manager)
         self.io_stats = {"scans": 0, "fused_scans": 0,
                          "device_expands": 0, "group_scans": 0,
                          "stack_rebuilds": 0, "sessions_closed": 0,
                          "sharded_group_scans": 0,
+                         "shard_gather_bytes": 0,
                          "two_stage_groups": 0,
                          "archive_trimmed_frames": 0,
                          "alerts_fired": 0, "alerts_suppressed": 0}

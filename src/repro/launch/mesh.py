@@ -44,9 +44,14 @@ def make_memory_mesh(shards: int = 0):
     slab axis), data=1. ``shards=0`` means every visible device. Under
     ``XLA_FLAGS=--xla_force_host_platform_device_count=K`` (set BEFORE
     jax initialises — the multi-device CI lane exports it as a job env
-    var) this gives K host-platform shards for equivalence testing."""
+    var) this gives K host-platform shards for equivalence testing.
+    Asking for more shards than there are devices raises ``ValueError``:
+    the arena sized for ``shards`` chips would not fit on fewer."""
     n = len(jax.devices())
-    return make_host_mesh(model=n if shards <= 0 else min(shards, n))
+    if shards > n:
+        raise ValueError(f"{shards} memory shards asked for, but only {n} "
+                         f"devices are visible")
+    return make_host_mesh(model=n if shards <= 0 else shards)
 
 
 def data_axes(mesh) -> Tuple[str, ...]:

@@ -218,7 +218,9 @@ class VenusService:
         count of the same), and ``kops_shard_gather_bytes`` — the bytes
         of per-shard scan OUTPUTS crossing shard boundaries at the
         candidate gather: O(S·Q·(T+K)) fused, no O(S·Q·capacity) term,
-        which is the whole point of scanning shard-locally.
+        which is the whole point of scanning shard-locally;
+        ``shard_gather_bytes`` is the same count for this manager's
+        groups alone.
         ``archive_trimmed_frames`` counts host frames the bounded
         ``FrameStore`` dropped below the live eviction windows.
 

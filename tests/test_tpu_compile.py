@@ -117,6 +117,30 @@ def test_fused_retrieve_sharded_compiles(topo):
     assert "all-gather" not in text and "all-reduce" not in text
 
 
+def test_fused_retrieve_sharded_compiles_at_fleet_size(topo):
+    """The four-chip fleet's fused scan: 64 slots of 65,536 float32 rows
+    at 768 dimensions, 16 per chip, an AKR group of 8 queries with 32
+    draw targets each. Each chip holds its 3.2 GB slab and nothing
+    crosses chips inside the program."""
+    mesh = Mesh(np.asarray(topo.devices).reshape(4), ("model",))
+    slab = NamedSharding(mesh, P("model"))
+    local = lambda q, x, v, t: tuple(fused_retrieve_scan_stack(
+        q, x, v, t, tau=0.1, n_topk=1, interpret=False))
+    sp = P("model", None, None)
+    fn = jax.shard_map(local, mesh=mesh,
+                       in_specs=(sp, sp, P("model", None), sp),
+                       out_specs=(sp,) * 7, check_vma=False)
+    slots, rows, q = 64, 65536, 8
+    compiled = _compile(fn, slab, ((slots, q, D), jnp.float32),
+                        ((slots, rows, D), jnp.float32),
+                        ((slots, 2), jnp.int32), ((slots, q, T), jnp.float32))
+    text = compiled.as_text()
+    assert "all-gather" not in text and "all-reduce" not in text
+    per_chip = compiled.memory_analysis().argument_size_in_bytes
+    slab_bytes = slots // 4 * rows * D * 4
+    assert slab_bytes <= per_chip < 1.01 * slab_bytes
+
+
 def test_gqa_decode_compiles(one_chip):
     """The smoke Qwen2-VL decode step: its GQA head layout against the
     serving engine's default cache (4 slots × 1024 positions, bf16 as
