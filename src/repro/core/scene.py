@@ -11,15 +11,17 @@ Two entry points:
   frames-since-boundary counter; returns a boundary mask and per-frame
   partition ids so downstream stages stay fixed-shape under jit.
 
-``StreamSegmenter`` is the online wrapper: it consumes chunks of frames,
-maintains carry state across chunks (the previous chunk's tail φ counter)
-and emits closed partitions.
+``StreamSegmenter`` is the online state of one stream: its carried last
+frame and the rule's counters across chunks. ``segment_streams`` runs
+one tick for many streams — one upload and one scene-score launch per
+chunk shape, one read of φ, then each stream's rule — and
+``StreamSegmenter.ingest`` is the same path for one stream.
 """
 
 from __future__ import annotations
 
 from dataclasses import dataclass, field
-from typing import List, Optional, Tuple
+from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -79,31 +81,12 @@ class StreamSegmenter:
     _open_start: int = 0
     _abs: int = 0
     _started: bool = False
-    _last_frame: Optional[jnp.ndarray] = None
+    # the last frame ingested, on the device in ``kops.lane_dense`` form
+    _last_frame: Optional[jax.Array] = None
 
-    def ingest(self, frames: jnp.ndarray) -> List[Partition]:
+    def ingest(self, frames) -> List[Partition]:
         """Consume a chunk (T,H,W,3); return partitions closed by it."""
-        if self._last_frame is not None:
-            ext = jnp.concatenate([self._last_frame[None], frames], axis=0)
-            phi = np.asarray(scene_scores(ext, self.weights))[1:]
-        else:
-            phi = np.asarray(scene_scores(frames, self.weights))
-        self._last_frame = frames[-1]
-        closed: List[Partition] = []
-        for i, p in enumerate(phi):
-            t = self._abs + i
-            is_boundary = (self._started
-                           and (p > self.threshold
-                                or self._since >= self.max_partition_len))
-            if is_boundary:
-                closed.append(Partition(self._open_start, t))
-                self._open_start = t
-                self._since = 1
-            else:
-                self._since += 1
-            self._started = True
-        self._abs += len(phi)
-        return closed
+        return segment_streams([self], [frames])[0]
 
     def flush(self) -> List[Partition]:
         if self._started and self._abs > self._open_start:
@@ -111,3 +94,73 @@ class StreamSegmenter:
             self._open_start = self._abs
             return part
         return []
+
+
+def segment_streams(segmenters: Sequence[StreamSegmenter],
+                    chunks: Sequence[np.ndarray],
+                    io_stats: Optional[Dict[str, int]] = None
+                    ) -> List[List[Partition]]:
+    """One tick of segmentation over several streams: ``chunks[i]``
+    (T,H,W,3) goes to ``segmenters[i]``; returns each one's closed
+    partitions.
+
+    Chunks of one (T, H, W) and weights go up in one ``device_put`` in
+    ``kops.lane_dense`` form and are scored by one
+    ``kops.scene_score_streams`` launch, each against its stream's
+    carried last frame, which stays on the device; every launch's φ
+    comes back in one read. A stream with no frame before this chunk
+    scores its first frame against zeros, and the rule ignores that φ.
+    ``io_stats``, when given, counts the launches
+    (``scene_score_launches``) and the streams they scored
+    (``scene_score_streams``)."""
+    groups: Dict[tuple, List[int]] = {}
+    for i, chunk in enumerate(chunks):
+        if len(chunk):
+            key = (tuple(np.shape(chunk)[:3]), tuple(segmenters[i].weights))
+            groups.setdefault(key, []).append(i)
+    launched = []
+    for (shape, weights), idx in groups.items():
+        dense = jax.device_put([kops.lane_dense(chunks[i]) for i in idx])
+        rows = dense[0].shape[0] // shape[0]
+        prev = [segmenters[i]._last_frame for i in idx]
+        prev = [jnp.zeros((rows, dense[0].shape[1]), jnp.float32)
+                if p is None else p for p in prev]
+        phi, last = kops.scene_score_streams(prev, dense, shape, weights)
+        for i, frame in zip(idx, last):
+            segmenters[i]._last_frame = frame
+        launched.append((idx, phi))
+    phis = jax.device_get([phi for _, phi in launched])
+    closed: List[List[Partition]] = [[] for _ in segmenters]
+    for (idx, _), phi in zip(launched, phis):
+        _apply_rule([segmenters[i] for i in idx], phi,
+                    [closed[i] for i in idx])
+    if io_stats is not None:
+        io_stats["scene_score_launches"] += len(launched)
+        io_stats["scene_score_streams"] += sum(len(i) for i, _ in launched)
+    return closed
+
+
+def _apply_rule(segs: Sequence[StreamSegmenter], phi: np.ndarray,
+                closed: Sequence[List[Partition]]) -> None:
+    """The boundary rule over φ (S, T), one row a stream, frame by frame
+    across all rows at once: frame t starts a partition when its stream
+    has a frame before it and φ exceeds the threshold, or the open
+    partition already holds ``max_partition_len`` frames. Appends each
+    stream's closed partitions to ``closed`` and advances its state."""
+    # float32 thresholds: φ is compared in its own precision
+    threshold = np.asarray([s.threshold for s in segs], phi.dtype)
+    max_len = np.asarray([s.max_partition_len for s in segs])
+    since = np.asarray([s._since for s in segs])
+    started = np.asarray([s._started for s in segs])
+    cut = np.zeros(phi.shape, bool)
+    for t in range(phi.shape[1]):
+        cut[:, t] = started & ((phi[:, t] > threshold) | (since >= max_len))
+        since = np.where(cut[:, t], 1, since + 1)
+        started[:] = True
+    for seg, row, out, n in zip(segs, cut, closed, since):
+        for t in np.flatnonzero(row) + seg._abs:
+            out.append(Partition(seg._open_start, int(t)))
+            seg._open_start = int(t)
+        seg._since = int(n)
+        seg._started = True
+        seg._abs += phi.shape[1]

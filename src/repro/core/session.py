@@ -58,7 +58,7 @@ from repro.core.memory import (ArenaStackView, FrameStore, MemoryArena,
                                MemoryStack, VenusMemory)
 from repro.core.queryplan import (QueryPlan, QueryResult, QuerySpec,
                                   build_plan, execute_plan)
-from repro.core.scene import Partition, StreamSegmenter
+from repro.core.scene import Partition, StreamSegmenter, segment_streams
 from repro.core.standing import Alert, StandingRegistry
 from repro.launch.mesh import make_memory_mesh
 from repro.launch.sharding import mesh_axis_size
@@ -226,15 +226,23 @@ class SessionState:
 # ---------------------------------------------------------------------------
 
 
-def segment_stage(state: SessionState, chunk: np.ndarray) -> List[Partition]:
-    """① scene segmentation: archive the chunk, return closed partitions."""
-    chunk = np.asarray(chunk, np.float32)
-    with span("ingest.segment.archive"):
-        state.frames.append(chunk)
-    state.stats["frames_seen"] += len(chunk)
-    with span("ingest.segment.scores"):
-        closed = state.segmenter.ingest(jnp.asarray(chunk))
-    state.pending.extend(chunk)
+def segment_stage(states: Sequence[SessionState],
+                  chunks: Sequence[np.ndarray],
+                  io_stats: Optional[Dict[str, int]] = None
+                  ) -> List[List[Partition]]:
+    """① scene segmentation of one tick: archive each stream's chunk,
+    score every chunk in one batched pass (``segment_streams``), return
+    each stream's closed partitions."""
+    chunks = [np.asarray(c, np.float32) for c in chunks]
+    for st, chunk in zip(states, chunks):
+        with span("ingest.segment.archive"):
+            st.frames.append(chunk)
+        st.stats["frames_seen"] += len(chunk)
+    with span("ingest.segment.scores", streams=len(states)):
+        closed = segment_streams([st.segmenter for st in states], chunks,
+                                 io_stats)
+    for st, chunk in zip(states, chunks):
+        st.pending.extend(chunk)
     return closed
 
 
@@ -403,7 +411,10 @@ class SessionManager:
         # (MUST stay 0 in arena mode — the zero-restack invariant);
         # sharded_group_scans counts launches that fanned out per slab
         # and shard_gather_bytes the epilogue bytes they stitched back
-        # from the slabs (kops' shard_gather_bytes, per manager)
+        # from the slabs (kops' shard_gather_bytes, per manager);
+        # scene_score_launches counts segmentation launches (one per
+        # chunk shape in a tick) and scene_score_streams the streams
+        # they scored
         self.io_stats = {"scans": 0, "fused_scans": 0,
                          "device_expands": 0, "group_scans": 0,
                          "stack_rebuilds": 0, "sessions_closed": 0,
@@ -411,7 +422,9 @@ class SessionManager:
                          "shard_gather_bytes": 0,
                          "two_stage_groups": 0,
                          "archive_trimmed_frames": 0,
-                         "alerts_fired": 0, "alerts_suppressed": 0}
+                         "alerts_fired": 0, "alerts_suppressed": 0,
+                         "scene_score_launches": 0,
+                         "scene_score_streams": 0}
         # standing queries: persistent per-session QuerySpecs evaluated
         # inside commit_jobs against each tick's newly committed rows
         # (one extra slab launch per tick — see repro.core.standing)
@@ -540,9 +553,9 @@ class SessionManager:
         with span("ingest_tick", streams=len(chunks),
                   frames=sum(len(c) for c in chunks.values())):
             with span("ingest.segment") as seg:
-                closed_by_sid = {
-                    sid: segment_stage(self.sessions[sid], chunk)
-                    for sid, chunk in chunks.items()}
+                closed_by_sid = dict(zip(chunks, segment_stage(
+                    [self.sessions[sid] for sid in chunks],
+                    list(chunks.values()), self.io_stats)))
             jobs: List[EmbedJob] = []
             with span("ingest.cluster") as clu:
                 for sid, closed in closed_by_sid.items():

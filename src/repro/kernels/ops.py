@@ -13,16 +13,18 @@ reference.
 from __future__ import annotations
 
 import functools
-from typing import NamedTuple, Optional, Tuple
+from typing import NamedTuple, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from repro.kernels import ref
 from repro.launch.sharding import mesh_axis_size
 
 _PLATFORM_BACKEND = {"tpu": "pallas", "cpu": "jnp"}
+_LANES = 128
 _OVERRIDE: Optional[str] = None
 
 
@@ -359,6 +361,61 @@ def scene_score(frames, weights) -> jnp.ndarray:
         return sk.scene_score(frames, tuple(weights),
                               interpret=_interpret())
     return ref.scene_score_ref(frames, tuple(weights))
+
+
+def lane_dense(frames: np.ndarray) -> np.ndarray:
+    """(T, H, W, 3) frames -> float32 (T·R, 128), R = ⌈H·W·3 / 128⌉ rows
+    a frame: row-major with a 128-wide minor axis, which the device lays
+    out as it lies in host memory. A view of contiguous float32 frames
+    whose H·W·3 is a multiple of 128; otherwise a copy with each frame
+    zero-padded to whole rows."""
+    flat = np.ascontiguousarray(frames, np.float32).reshape(len(frames), -1)
+    pad = -flat.shape[1] % _LANES
+    if pad:
+        flat = np.pad(flat, ((0, 0), (0, pad)))
+    return flat.reshape(-1, _LANES)
+
+
+def scene_score_streams(prev: Sequence[jax.Array],
+                        chunks: Sequence[jax.Array],
+                        shape: Tuple[int, int, int],
+                        weights) -> Tuple[jax.Array, Tuple[jax.Array, ...]]:
+    """One program scores S streams' chunks of ``shape`` = (T, H, W).
+
+    ``chunks[s]`` is stream s's T frames in ``lane_dense`` form, on the
+    device; ``prev[s]`` is the frame before them in the same form (one
+    frame's R rows). Returns φ (S, T), φ[s, i] scoring chunk s's frame i
+    against the frame before it, and each chunk's last frame, the next
+    tick's ``prev``. The frames run through ``scene_score`` laid end to
+    end, each stream's ``prev`` before its chunk, so every φ comes from
+    the same per-frame arithmetic as that stream's own ``scene_score``
+    call; each stream's leading φ, scored against the stream before it,
+    is dropped."""
+    return _scene_score_streams(tuple(prev), tuple(chunks),
+                                shape=tuple(shape), weights=tuple(weights),
+                                backend=backend(), interpret=_interpret())
+
+
+@functools.partial(jax.jit, static_argnames=("shape", "weights", "backend",
+                                             "interpret"))
+def _scene_score_streams(prev, chunks, *, shape, weights, backend,
+                         interpret):
+    t, h, w = shape
+    n = h * w * 3
+
+    def frames(x, k):
+        return x.reshape(k, -1)[:, :n].reshape(k, h, w, 3)
+
+    seq = jnp.concatenate([f for p, x in zip(prev, chunks)
+                           for f in (frames(p, 1), frames(x, t))])
+    if backend == "pallas":
+        from repro.kernels import scene_score as sk
+        phi = sk.scene_score(seq, weights, interpret=interpret)
+    else:
+        phi = ref.scene_score_ref(seq, weights)
+    rows = prev[0].shape[0]
+    return (phi.reshape(len(chunks), t + 1)[:, 1:],
+            tuple(x[(t - 1) * rows:] for x in chunks))
 
 
 def _largest_divisor_blk(n: int, target: int) -> int:

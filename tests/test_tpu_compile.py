@@ -21,6 +21,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
 from repro.configs import registry
+from repro.kernels import ops
 from repro.kernels.decode_attention import gqa_decode, mla_decode
 from repro.kernels.scene_score import scene_score
 from repro.kernels.similarity import (fused_retrieve_scan_stack,
@@ -97,6 +98,26 @@ def test_scene_score_compiles(one_chip):
     """One ingest chunk of 224² frames (8 frames plus the carried one)."""
     fn = lambda f: scene_score(f, (1.0, 1.0, 1.0, 2.0), interpret=False)
     _compile(fn, one_chip, ((9, 224, 224, 3), jnp.float32))
+
+
+@pytest.mark.parametrize("streams,frames", [(16, 8), (64, 2)])
+def test_scene_score_streams_compiles(one_chip, streams, frames):
+    """One ingest tick's scene scores in one program: the ingest cell's
+    16 streams of 8 frames and the query cells' 64 of 2, 224² float32.
+    Chunks and carried frames enter as 128-wide float32 rows, which the
+    chip lays out with no padding: the arguments are the frames' raw
+    bytes."""
+    rows = 224 * 224 * 3 // 128
+    fn = lambda prev, chunks: ops._scene_score_streams(
+        prev, chunks, shape=(frames, 224, 224),
+        weights=(1.0, 1.0, 1.0, 2.0), backend="pallas", interpret=False)
+    args = [tuple(jax.ShapeDtypeStruct((k * rows, 128), jnp.float32,
+                                       sharding=one_chip)
+                  for _ in range(streams)) for k in (1, frames)]
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    raw = streams * (frames + 1) * 224 * 224 * 3 * 4
+    assert compiled.memory_analysis().argument_size_in_bytes == raw
 
 
 def test_fused_retrieve_sharded_compiles(topo):
